@@ -1,8 +1,9 @@
 """Jitted public wrappers around the Pallas kernels.
 
-On TPU the kernels compile natively; on CPU (this container) they execute in
-``interpret=True`` mode — same kernel body, Python-interpreted — which is the
-validation path the tests exercise against the ref.py oracles.
+On TPU the kernels compile natively; on the CPU, where the tests run, they
+execute in ``interpret=True`` mode — same kernel body, Python-interpreted —
+which is the validation path the tests exercise against the ref.py oracles.
+Any other backend is an error rather than a silent interpreter run.
 """
 from __future__ import annotations
 
@@ -18,8 +19,13 @@ from .flash_attention import flash_attention_pallas
 from .paged_attention import paged_attention_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(f"Pallas kernels compile for tpu and are "
+                           f"interpreted on cpu; backend {backend!r} is "
+                           f"neither")
+    return backend == "cpu"
 
 
 # ---------------------------------------------------------------------------
@@ -29,7 +35,7 @@ def _on_tpu() -> bool:
 def seal(x, key, counter, use_kernel: bool = False):
     """Quantize+encrypt a 2D activation. Returns (cipher u8, scales f32)."""
     if use_kernel:
-        return seal_pallas(x, key, counter, interpret=not _on_tpu())
+        return seal_pallas(x, key, counter, interpret=_interpret())
     return ref.seal_ref(x, key, counter)
 
 
@@ -38,7 +44,7 @@ def unseal(cipher, scales, key, counter, out_dtype=jnp.bfloat16,
            use_kernel: bool = False):
     if use_kernel:
         return unseal_pallas(cipher, scales, key, counter,
-                             out_dtype=out_dtype, interpret=not _on_tpu())
+                             out_dtype=out_dtype, interpret=_interpret())
     return ref.unseal_ref(cipher, scales, key, counter, out_dtype)
 
 
@@ -48,7 +54,7 @@ def seal_bits(x, key, counter, use_kernel: bool = False):
     Unlike ``seal`` there is no quantization: ``unseal_bits`` restores the
     input bit-exactly (the KV swap tier's correctness contract)."""
     if use_kernel:
-        return seal_bits_pallas(x, key, counter, interpret=not _on_tpu())
+        return seal_bits_pallas(x, key, counter, interpret=_interpret())
     return ref.seal_bits_ref(x, key, counter)
 
 
@@ -57,7 +63,7 @@ def unseal_bits(cipher, key, counter, out_dtype=jnp.bfloat16,
                 use_kernel: bool = False):
     if use_kernel:
         return unseal_bits_pallas(cipher, key, counter, out_dtype=out_dtype,
-                                  interpret=not _on_tpu())
+                                  interpret=_interpret())
     return ref.unseal_bits_ref(cipher, key, counter, out_dtype)
 
 
@@ -78,7 +84,7 @@ def flash_attention(q, k, v, causal: bool = True, window: int = 0,
     vf = vv.transpose(0, 2, 1, 3).reshape(B * H, -1, D)
     if use_kernel:
         out = flash_attention_pallas(qf, kf, vf, causal=causal, window=window,
-                                     interpret=not _on_tpu())
+                                     interpret=_interpret())
     else:
         out = ref.flash_attention_ref(
             qf.reshape(B, H, S, D), kf.reshape(B, H, -1, D),
@@ -98,7 +104,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
     seq_lens: [B] int32. Returns [B, H, D].
 
     use_kernel=True routes to the fused Pallas kernel (block-table-driven
-    page DMA, interpret mode off-TPU); default is the jnp page-gather
+    page DMA, interpret mode on the CPU); default is the jnp page-gather
     oracle, which doubles as the portable fast path."""
     if use_kernel:
         B, H, D = q.shape
@@ -106,7 +112,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
         rep = H // KVH
         out = paged_attention_pallas(
             q.reshape(B, KVH, rep, D), k_pages, v_pages,
-            block_tables, seq_lens, interpret=not _on_tpu())
+            block_tables, seq_lens, interpret=_interpret())
         return out.reshape(B, H, D)
     return ref.paged_attention_ref(q, k_pages, v_pages, block_tables,
                                    seq_lens)

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCHS, SHAPES, get_arch, get_shape, shape_applicable
-from repro.launch.mesh import make_production_mesh, mesh_context
+from repro.launch.mesh import make_production_mesh
 from repro.models.api import build_model
 from repro.optim.adamw import AdamWConfig
 from repro.runtime import steps as S
@@ -57,7 +57,7 @@ def lower_cell(arch_name: str, shape_name: str, *, multi_pod: bool,
     abstract = S.abstract_inputs(api, shape)
 
     t0 = time.time()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             step = S.make_train_step(api, mesh, opt_cfg, shape,
                                      act_rules=act_rules,

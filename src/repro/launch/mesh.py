@@ -3,40 +3,28 @@ module-level constant) so importing this module touches no jax device state.
 """
 from __future__ import annotations
 
-import contextlib
+import math
 
 import jax
-
-try:
-    from jax.sharding import AxisType
-except ImportError:          # older jax: meshes are implicitly all-Auto
-    AxisType = None
-
-
-def _mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(AxisType.Auto,) * len(axes))
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 two-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Arbitrary test mesh (e.g. (2, 2) x ('pod', 'data') on CPU)."""
-    return _mesh(tuple(shape), tuple(axes))
-
-
-def mesh_context(mesh):
-    """``jax.set_mesh(mesh)`` where it exists (jax >= 0.6). Older jax has no
-    ambient-mesh API, and none is needed there: every step builder threads
-    its mesh explicitly through NamedSharding / axis_rules, so the context
-    degrades to a no-op instead of an ImportError."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return contextlib.nullcontext()
+    """Arbitrary mesh (e.g. (2, 2) x ('pod', 'data')) over the first
+    ``prod(shape)`` devices. A mesh larger than the device count is an
+    error naming both numbers."""
+    shape, axes = tuple(shape), tuple(axes)
+    devices = jax.devices()
+    need = math.prod(shape)
+    if need > len(devices):
+        raise ValueError(f"mesh {'x'.join(map(str, shape))} needs {need} "
+                         f"devices, but only {len(devices)} are available")
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices[:need])

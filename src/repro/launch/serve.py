@@ -3,6 +3,10 @@
   PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-1b --reduced \\
       --mesh 2x2 --stages 2 --microbatches 2 --slots 4 --requests 6
 
+``--stages 1`` serves on one device with no mesh (the one-chip entry
+point); more stages build a ``pod x data`` mesh (``--mesh``, default
+``<stages>x1``), and a mesh larger than the device count is an error.
+
 Thin CLI over ``repro.serving.ServingEngine`` (DESIGN.md §Serving engine):
 plans a ``PlacementSpec`` over the registered trust domains (``--topology``
 picks the registry; ``--space segment`` is the default PlacementSpec search,
@@ -65,8 +69,8 @@ from repro.core.privacy import LM_SIM_DELTA
 from repro.enclave.domain import sandwich_manager, two_enclave_manager
 from repro.launch.mesh import make_mesh
 from repro.models.api import build_model
-from repro.serving import (EngineConfig, FaultConfig, ServingEngine,
-                           pipelined_backend_available)
+from repro.serving import EngineConfig, FaultConfig, ServingEngine
+from repro.utils.compile_cache import enable_compile_cache
 
 TOPOLOGIES = {
     "two-enclave": lambda stages: two_enclave_manager(),
@@ -81,7 +85,9 @@ def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
-    ap.add_argument("--mesh", default="2x1", help="pod x data")
+    ap.add_argument("--mesh", default="",
+                    help="pod x data (default <stages>x1; built only when "
+                         "--stages > 1 and the backend is not local)")
     ap.add_argument("--stages", type=int, default=2)
     ap.add_argument("--microbatches", type=int, default=2)
     ap.add_argument("--slots", type=int, default=4,
@@ -296,15 +302,19 @@ def _serve_stream(eng: ServingEngine, args, cfg):
         moved = eng.step()
         if eng.stalled:
             # permanent back-pressure (legacy timeline exhausted): the FIFO
-            # head can never run, so later submissions can't either — stop
-            # driving gracefully (engine steps are frozen; waiting or
-            # submitting more would spin forever)
-            break
+            # head can never run, so later submissions can't either
+            _raise_stalled(reqs, len(prompts), eng.stall_reason)
         if k < len(prompts) and not moved and not eng.scheduler.has_work():
             # idle tick with arrivals pending: admit next immediately
             reqs.append(eng.submit(prompts[k], args.max_new))
             k += 1
     return reqs
+
+
+def _raise_stalled(reqs, n_prompts: int, reason) -> None:
+    done = sum(1 for r in reqs if r.status == "done")
+    raise RuntimeError(f"engine stalled ({reason}): {done} of {n_prompts} "
+                       f"requests finished, {len(reqs)} submitted")
 
 
 def _serve_stream_orch(orch, args, cfg):
@@ -320,7 +330,7 @@ def _serve_stream_orch(orch, args, cfg):
         orch.step()
         if orch.decode.stalled and not (
                 orch.prefill is not None and orch.prefill.has_work()):
-            break
+            _raise_stalled(reqs, len(prompts), orch.decode.stall_reason)
         if k < len(prompts) and not orch.has_work():
             reqs.append(orch.submit(prompts[k], args.max_new))
             k += 1
@@ -365,7 +375,7 @@ def _disagg_main(api, params, mesh, args, cfg):
                 ("decode", st["post_warmup_compiles"], st["compile_stalls"]),
                 ("prefill", ps["post_warmup_compiles"],
                  orch.eng_prefill.stats()["compile_stalls"])):
-            assert n in (None, 0), \
+            assert n == 0, \
                 f"{side}: {n} XLA compilations after warmup (stalls: " \
                 f"{stalls})"
             assert not stalls, (side, stalls)
@@ -419,6 +429,7 @@ def _disagg_main(api, params, mesh, args, cfg):
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = reduce_cfg(cfg)
@@ -427,8 +438,9 @@ def main(argv=None):
         L.DEFAULT_DTYPE = jnp.float32
 
     mesh = None
-    if args.backend != "local" and pipelined_backend_available():
-        dims = tuple(int(x) for x in args.mesh.split("x"))
+    if args.stages > 1 and args.backend != "local":
+        dims = tuple(int(x) for x in (args.mesh or f"{args.stages}x1")
+                     .split("x"))
         mesh = make_mesh(dims, ("pod", "data")[:len(dims)])
 
     api = build_model(cfg, max_seq=args.max_seq or 512)
@@ -506,14 +518,11 @@ def main(argv=None):
         # engine's post-freeze window
         assert args.warmup, "--assert-no-recompile needs --warmup"
         n = st["post_warmup_compiles"]
-        # None = the compile monitor could not hook this jax version; the
-        # registry's own stall ledger still covers managed functions
-        assert n in (None, 0), \
+        assert n == 0, \
             f"{n} XLA compilations after warmup; stalls: " \
             f"{st['compile_stalls']}"
         assert not st["compile_stalls"], st["compile_stalls"]
-        print(f"NO-RECOMPILE OK: post_warmup_compiles="
-              f"{'unavailable' if n is None else n}, 0 stalls")
+        print(f"NO-RECOMPILE OK: post_warmup_compiles={n}, 0 stalls")
 
     if args.verify_swap:
         assert args.no_seal, "--verify-swap needs --no-seal (see docstring)"

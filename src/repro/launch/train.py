@@ -19,7 +19,7 @@ import numpy as np
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs import get_arch, reduced as reduce_cfg, ShapeConfig
 from repro.data.tokens import SyntheticTokenStream
-from repro.launch.mesh import make_mesh, make_production_mesh, mesh_context
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.models.api import build_model
 from repro.optim import adamw
 from repro.optim.adamw import AdamWConfig
@@ -67,7 +67,7 @@ def main(argv=None):
     opt_state = adamw.init(params)
     data = SyntheticTokenStream(cfg.vocab_size, args.batch, args.seq)
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         step = S.make_train_step(api, mesh, opt_cfg, shape,
                                  compress_pod_grads=args.compress_pod_grads)
         # place state on its training shardings (required on multi-device

@@ -12,8 +12,10 @@ boundary compression.
 
 Implementation: ``jax.shard_map`` manual over {pod} only — data/model axes
 stay GSPMD-managed inside each stage, so TP/EP/sequence-sharded caches
-compose with pipelining. The tick loop is a ``lax.scan``; communication is
-one ``ppermute`` ring per tick.
+compose with pipelining. With ``use_kernel`` (Pallas attention and seal
+kernels, which cannot be partitioned automatically) every axis is manual.
+The tick loop is a ``lax.scan``; communication is one ``ppermute`` ring
+per tick.
 
 Applicability: any model whose body is ONE homogeneous scanned segment
 (dense, VLM, Qwen-MoE, xLSTM, Hymba). Moonshot's dense stem and Whisper's
@@ -47,6 +49,7 @@ would discard the warmed dispatch caches with it.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Optional, Sequence
@@ -143,17 +146,27 @@ class PipelinedDecoder:
             [np.arange(self.bps) < counts[s] for s in range(S)])
 
     # -- parameter / cache reshaping (leading stage dim, sharded over pod) --
+    def _on_pods(self, x):
+        """Pin a staged leaf's stage dim to the pod axis (stage s on pod s),
+        inside a jit or eagerly; a decoder without a mesh only reshapes."""
+        if self.mesh is None:
+            return x
+        return jax.lax.with_sharding_constraint(
+            x, NamedSharding(self.mesh, P("pod", *([None] * (x.ndim - 1)))))
+
     def _stage_tree(self, tree):
         """[n_blocks, ...] leaves -> [num_stages, bps, ...] (gather-padded
-        when stages are uneven, plain reshape when even)."""
+        when stages are uneven, plain reshape when even), stage dim on the
+        pod axis."""
         S, bps = self.num_stages, self.bps
         if self.uniform:
             return jax.tree.map(
-                lambda x: x.reshape((S, bps) + x.shape[1:]), tree)
+                lambda x: self._on_pods(x.reshape((S, bps) + x.shape[1:])),
+                tree)
         idx = jnp.asarray(self._gather_idx)
         return jax.tree.map(
-            lambda x: jnp.take(x, idx, axis=0).reshape(
-                (S, bps) + x.shape[1:]), tree)
+            lambda x: self._on_pods(jnp.take(x, idx, axis=0).reshape(
+                (S, bps) + x.shape[1:])), tree)
 
     def stage_params(self, params):
         seg = dict(params)
@@ -188,9 +201,9 @@ class PipelinedDecoder:
         S2, bps2 = new_dec.num_stages, new_dec.bps
         idx = jnp.asarray(self._scatter_idx[new_dec._gather_idx])
         new_body = jax.tree.map(
-            lambda x: jnp.take(
+            lambda x: new_dec._on_pods(jnp.take(
                 x.reshape((self.num_stages * self.bps,) + x.shape[2:]),
-                idx, axis=0).reshape((S2, bps2) + x.shape[2:]), body)
+                idx, axis=0).reshape((S2, bps2) + x.shape[2:])), body)
         return (new_body, *rest)
 
     # -- specs ---------------------------------------------------------------
@@ -201,6 +214,31 @@ class PipelinedDecoder:
             return P(*([None] * x.ndim))
         return {k: jax.tree.map(functools.partial(spec, k == self.seg.name), v)
                 for k, v in staged.items()}
+
+    # -- placement: commit staged trees to the mesh ONCE, outside the step ---
+    def place_params(self, params):
+        """Stage ``params`` and commit them to the mesh: stage s's blocks on
+        pod s, embedding / final norm replicated. Committed arrays that
+        already match the step's shard_map specs are never moved again, so
+        no step copies the model."""
+        staged = self.stage_params(params)
+        return {k: v if k == self.seg.name else self.place_replicated(v)
+                for k, v in staged.items()}
+
+    def place_replicated(self, tree):
+        """Commit a tree replicated over the mesh (block tables, lengths)."""
+        return jax.tree.map(
+            lambda x: jax.device_put(x, NamedSharding(self.mesh, P())), tree)
+
+    @staticmethod
+    def stage_shard(tree, s: int):
+        """Stage s's [1, bps, ...] block of a pod-sharded staged tree, read
+        on the device that holds it (no gather), plus that device."""
+        leaves, treedef = jax.tree.flatten(tree)
+        shards = [next(sh for sh in a.addressable_shards
+                       if sh.index[0].start == s) for a in leaves]
+        return (jax.tree.unflatten(treedef, [sh.data for sh in shards]),
+                shards[0].device)
 
     # -- one stage's block scan (shared by the tick loop and the telemetry
     # -- stage probe) --------------------------------------------------------
@@ -242,19 +280,25 @@ class PipelinedDecoder:
         ``probe(blk_params, blk_cache, blk_mask, x, cache_len)`` executes one
         stage's block scan exactly as a pipeline tick would (minus seal /
         ppermute) so the host can time each stage independently. The caller
-        slices stage s out of the prestaged trees (``tree[s]``) and times
-        ``jax.block_until_ready(probe(...))``. With ``paged=True`` the
-        signature is ``probe(blk_params, blk_pool, blk_mask, x, bt, sl)``
-        (whole-pool stage slice, block table + seq_lens for the probed
-        rows)."""
+        passes stage s's [1, bps, ...] shards (``stage_shard``) and the
+        other inputs on that stage's device, and times
+        ``jax.block_until_ready(probe(...))`` — stage s runs on its own
+        chip. With ``paged=True`` the signature is ``probe(blk_params,
+        blk_pool, blk_mask, x, bt, sl)`` (whole-pool stage shard, block
+        table + seq_lens for the probed rows)."""
+        def first(tree):
+            return jax.tree.map(lambda a: a[0], tree)
+
         if paged:
             def probe(blk_params, blk_cache, blk_mask, x, bt, sl):
-                h, _ = self._stage_run(blk_params, blk_cache, blk_mask, x,
-                                       None, paged=(bt, sl))
+                h, _ = self._stage_run(first(blk_params), first(blk_cache),
+                                       blk_mask, x, None, paged=(bt, sl))
                 return h
         else:
             def probe(blk_params, blk_cache, blk_mask, x, cache_len):
-                h, _ = self._stage_run(blk_params, blk_cache, blk_mask, x,
+                rows = jax.tree.map(lambda a: a[0, :, :x.shape[0]],
+                                    blk_cache)
+                h, _ = self._stage_run(first(blk_params), rows, blk_mask, x,
                                        cache_len)
                 return h
         return jax.jit(probe)
@@ -449,14 +493,20 @@ class PipelinedDecoder:
             cache_specs = jax.tree.map(
                 lambda x: P("pod", *([None] * (x.ndim - 1))), staged_cache)
             body = functools.partial(pipeline_body)
-
-            with R.axis_rules(mesh, R.PIPE_RULES):
+            # a Pallas kernel cannot be partitioned automatically, so with
+            # use_kernel every mesh axis is manual (each stage's data
+            # replicas compute the same) and no GSPMD rule applies inside
+            if use_kernel:
+                manual, rules = set(mesh.axis_names), contextlib.nullcontext()
+            else:
+                manual, rules = {"pod"}, R.axis_rules(mesh, R.PIPE_RULES)
+            with rules:
                 outputs, new_cache = jax.shard_map(
                     body, mesh=mesh,
                     in_specs=(param_specs, cache_specs, P("pod", None),
                               P(), starts_spec, P(), P()),
                     out_specs=(P("pod"), cache_specs),
-                    axis_names={"pod"}, check_vma=False,
+                    axis_names=manual, check_vma=False,
                 )(staged_params, staged_cache, stage_mask, tok_stream,
                   starts, cache_len, key)
             # stages stack outputs along dim 0; the last nm rows are real

@@ -46,8 +46,7 @@ from .disagg import (DisaggOrchestrator, PrefillEngine, RoleCandidate,
 from .faults import FaultConfig, FaultPlane
 from .engine import (EngineConfig, EngineEvent, LocalDecodeBackend,
                      PagedLocalBackend, PagedPipelinedBackend,
-                     PipelinedDecodeBackend, ServingEngine,
-                     pipelined_backend_available)
+                     PipelinedDecodeBackend, ServingEngine)
 from .sampling import TokenSampler
 from .scheduler import (HANDOFF, PagePool, Request, SlotScheduler,
                         TransferManifest)
@@ -61,5 +60,5 @@ __all__ = [
     "PagedPipelinedBackend", "PipelinedDecodeBackend", "PrefillEngine",
     "Request", "RoleCandidate", "RolePlan", "ServingEngine", "SlotScheduler",
     "StageTelemetry", "TokenSampler", "TransferManifest", "build_disagg",
-    "pipelined_backend_available", "plan_disagg_roles",
+    "plan_disagg_roles",
 ]

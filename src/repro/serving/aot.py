@@ -6,13 +6,12 @@ jax equivalent by AOT-compiling the generate step and every prefill bucket
 at warmup. This module is the machinery behind ``ServingEngine.warmup()``
 (DESIGN.md §AOT warmup & chunked prefill):
 
-* ``CompileMonitor`` — process-wide compile counters. It wraps
-  ``jax._src.compiler.backend_compile`` (true XLA compilations) and
-  ``mlir.lower_jaxpr_to_module`` (trace+lower events), so "zero new
-  compilations after warmup" is *asserted against the runtime*, not
-  inferred from our own bookkeeping. Wrapping is guarded: if a jax upgrade
-  moves those internals the monitor degrades to ``available=False`` and
-  the engine's assertions become no-ops instead of crashes.
+* ``CompileMonitor`` — a process-wide compile counter. It listens to
+  jax's public ``jax.monitoring`` duration event for backend compilation
+  (``/jax/core/compile/backend_compile_duration``, recorded around every
+  XLA compile or persistent-cache load), so "zero new compilations after
+  warmup" is *asserted against the runtime*, not inferred from our own
+  bookkeeping.
 * ``AotFn`` — one managed jitted function. ``warm(*args)`` runs
   ``fn.lower(*args).compile()`` and records the call signature (flattened
   leaf avals + treedef). Dispatch mode:
@@ -45,51 +44,30 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 
 
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
 class CompileMonitor:
-    """Process-global compile counters via guarded monkeypatch of jax
-    internals. ``install()`` is idempotent; counters are monotonic for the
+    """Process-global XLA compile counter fed by a ``jax.monitoring``
+    listener. ``install()`` is idempotent; the counter is monotonic for the
     process lifetime (consumers snapshot baselines, never reset)."""
 
     def __init__(self):
         self.installed = False
-        self.available = False
-        self.backend_compiles = 0       # true XLA compilations
-        self.lowerings = 0              # trace+lower events (cache misses)
+        self.backend_compiles = 0       # XLA compilations (incl. cache loads)
 
-    def install(self) -> bool:
-        if self.installed:
-            return self.available
-        self.installed = True
-        try:
-            from jax._src import compiler as _compiler
-            orig_bc = _compiler.backend_compile
+    def _on_duration(self, event: str, duration: float, **kw) -> None:
+        if event == BACKEND_COMPILE_EVENT:
+            self.backend_compiles += 1
 
-            def counted_bc(*a, **kw):
-                self.backend_compiles += 1
-                return orig_bc(*a, **kw)
-
-            _compiler.backend_compile = counted_bc
-            self.available = True
-        except Exception:               # pragma: no cover - jax internals
-            self.available = False
-        try:
-            from jax._src.interpreters import mlir as _mlir
-            orig_low = _mlir.lower_jaxpr_to_module
-
-            def counted_low(*a, **kw):
-                self.lowerings += 1
-                return orig_low(*a, **kw)
-
-            _mlir.lower_jaxpr_to_module = counted_low
-        except Exception:               # pragma: no cover - jax internals
-            pass
-        return self.available
-
-    def counts(self) -> Tuple[int, int]:
-        return self.backend_compiles, self.lowerings
+    def install(self) -> None:
+        if not self.installed:
+            jax.monitoring.register_event_duration_secs_listener(
+                self._on_duration)
+            self.installed = True
 
 
-#: one monitor per process — backend_compile is global state
+#: one monitor per process — jax's listener registry is global state
 MONITOR = CompileMonitor()
 
 
@@ -165,7 +143,7 @@ class AotRegistry:
         self.monitor = monitor or MONITOR
         self.fns: Dict[str, AotFn] = {}
         self.frozen = False
-        self._baseline: Optional[Tuple[int, int]] = None
+        self._baseline: Optional[int] = None
         self.stalls: List[CompileStall] = []
 
     def wrap(self, name: str, fn: Callable,
@@ -180,17 +158,18 @@ class AotRegistry:
     def freeze(self) -> None:
         """Warmup done: snapshot the monitor so ``post_freeze_compiles``
         counts only what happens during steady-state serving."""
+        self.monitor.install()
         self.frozen = True
-        self._baseline = self.monitor.counts()
+        self._baseline = self.monitor.backend_compiles
 
     @property
     def post_freeze_compiles(self) -> Optional[int]:
-        """XLA compiles since freeze() — None when never frozen or the
-        monitor could not hook this jax version. NOTE: process-global;
-        another engine warming up after this one froze shows up here."""
-        if self._baseline is None or not self.monitor.available:
+        """XLA compiles since freeze() — None only when never frozen.
+        NOTE: process-global; another engine warming up after this one
+        froze shows up here."""
+        if self._baseline is None:
             return None
-        return self.monitor.backend_compiles - self._baseline[0]
+        return self.monitor.backend_compiles - self._baseline
 
     @property
     def post_freeze_stalls(self) -> List[CompileStall]:

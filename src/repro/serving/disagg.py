@@ -196,12 +196,11 @@ class PrefillEngine:
                             List[Request]]:
         """One prefill round. Returns (handoffs, completed_at_prefill)."""
         eng = self.eng
-        with eng._mesh_ctx():
-            eng._admit()
-            eng._advance_chunks()
-            handoffs = []
-            for slot, req in list(eng.scheduler.decoding()):
-                handoffs.append(eng.export_transfer(slot))
+        eng._admit()
+        eng._advance_chunks()
+        handoffs = []
+        for slot, req in list(eng.scheduler.decoding()):
+            handoffs.append(eng.export_transfer(slot))
         done = eng.scheduler.completed_total - self._completed_seen
         completed: List[Request] = []
         if done:

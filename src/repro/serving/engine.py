@@ -30,8 +30,8 @@ request server (the paper's Fig. 2 loop as a service):
   ``PipelinedDecodeBackend`` run the shard_map pipelined decoder over the
   ``pod`` axis (stage boundaries from the placement solver, sealed
   boundaries); ``PagedLocalBackend`` / ``LocalDecodeBackend`` are the
-  single-process fallbacks used on hosts whose jax lacks
-  ``shard_map``/``set_mesh`` and for ``num_stages == 1``.
+  single-device backends, used when there is no mesh or
+  ``num_stages == 1``.
 * **telemetry → live re-plan swap** — every ``telemetry.interval`` steps the
   engine probes per-stage wall time, feeds ``OnlineReplanner.observe()``,
   and on a re-plan builds a decoder for the new boundaries and migrates the
@@ -41,7 +41,6 @@ request server (the paper's Fig. 2 loop as a service):
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import functools
 import itertools
@@ -53,6 +52,7 @@ from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.core.planner import (InfeasibleError, PlacementSpec,
                                 profiles_from_arch)
@@ -67,11 +67,6 @@ from repro.serving.sampling import TokenSampler
 from repro.serving.scheduler import (QUEUED, RUNNING, PagePool, Request,
                                      SlotScheduler, TransferManifest)
 from repro.serving.telemetry import StageTelemetry
-
-
-def pipelined_backend_available() -> bool:
-    """The shard_map pipelined decoder needs jax >= 0.6 APIs."""
-    return hasattr(jax, "shard_map") and hasattr(jax, "set_mesh")
 
 
 @dataclasses.dataclass
@@ -298,7 +293,7 @@ class PipelinedDecodeBackend:
                 num_microbatches=cfg.num_microbatches,
                 seal_boundary=cfg.seal_boundary, use_kernel=cfg.use_kernel,
                 stage_blocks=key)
-            staged_params = dec.stage_params(self.params)
+            staged_params = dec.place_params(self.params)
             # shard_map state changes sharding between the first and
             # steady-state call -> "jit" dispatch (serving/aot.py)
             step_fn = self.aot.wrap(f"step{key}", jax.jit(dec.build(
@@ -316,7 +311,7 @@ class PipelinedDecodeBackend:
         cache["len"] = jnp.int32(cfg.prompt_capacity)
         staged, cache_len = self.dec.stage_cache(cache)
         start = jnp.full((cfg.num_slots,), cfg.prompt_capacity, jnp.int32)
-        self.state = (staged, cache_len, start)
+        self.state = (staged, *self.dec.place_replicated((cache_len, start)))
 
     @property
     def cache_len(self) -> int:
@@ -351,14 +346,13 @@ class PipelinedDecodeBackend:
         cfg = self.cfg
         staged, cache_len, _ = self.state
         b_mb = cfg.num_slots // cfg.num_microbatches
-        x = jnp.zeros((b_mb, 1, self.api.cfg.d_model), L.DEFAULT_DTYPE)
-        mask = jnp.asarray(self.dec._mask)
+        x = np.zeros((b_mb, 1, self.api.cfg.d_model), L.DEFAULT_DTYPE)
         per_stage = []
         for s in range(cfg.num_stages):
-            blk_p = jax.tree.map(lambda a: a[s],
-                                 self.staged_params[self.seg.name])
-            blk_c = jax.tree.map(lambda a: a[s, :, :b_mb], staged)
-            args = (blk_p, blk_c, mask[s], x, cache_len)
+            (blk_p, blk_c), dev = self.dec.stage_shard(
+                (self.staged_params[self.seg.name], staged), s)
+            args = (blk_p, blk_c, *jax.device_put(
+                (self.dec._mask[s], x, np.asarray(cache_len)), dev))
             if not self._probe_warm:
                 jax.block_until_ready(self._probe(*args))
             t0 = time.perf_counter()
@@ -652,9 +646,17 @@ class PagedPipelinedBackend:
         → drop-scatter) for swap-in. Page ids are layout-invariant, so the
         host-side swap manifest is oblivious to staging — the same contract
         as restage_cache, and a manifest written under one stage layout
-        restores correctly after a live boundary swap."""
+        restores correctly after a live boundary swap. The cipher runs
+        replicated inside a shard_map over the whole mesh: its Pallas kernel
+        cannot be partitioned automatically."""
         use_kernel = self.cfg.use_kernel
         S, bps, n = dec.num_stages, dec.bps, dec.seg.n
+
+        def replicated(fn, **kw):
+            return jax.shard_map(functools.partial(fn, use_kernel=use_kernel,
+                                                   **kw),
+                                 mesh=self.mesh, in_specs=P(), out_specs=P(),
+                                 check_vma=False)
         if dec.uniform:
             def unstage(x):
                 return x.reshape((n,) + x.shape[2:])
@@ -671,8 +673,7 @@ class PagedPipelinedBackend:
             def sealed(pool_st, part):
                 g = unstage(pool_st)[:, pages].transpose(1, 0, 2, 3, 4)
                 g = g.reshape(pages.shape[0], -1)
-                return sealing.seal_pages(g, key, ctr, part=part,
-                                          use_kernel=use_kernel)
+                return replicated(sealing.seal_pages, part=part)(g, key, ctr)
 
             return sealed(k_st, 0), sealed(v_st, 1)
 
@@ -680,8 +681,9 @@ class PagedPipelinedBackend:
             k_st, v_st = staged
 
             def restored(pool_st, c, part):
-                rows = sealing.unseal_pages(c, key, ctr, pool_st.dtype,
-                                            part=part, use_kernel=use_kernel)
+                rows = replicated(sealing.unseal_pages,
+                                  out_dtype=pool_st.dtype, part=part)(
+                                      c, key, ctr)
                 g = rows.reshape(pages.shape[0], n, pool_st.shape[3],
                                  pool_st.shape[4], pool_st.shape[5])
                 g_st = dec._stage_tree(g.transpose(1, 0, 2, 3, 4))
@@ -701,7 +703,7 @@ class PagedPipelinedBackend:
                 num_microbatches=cfg.num_microbatches,
                 seal_boundary=cfg.seal_boundary, use_kernel=cfg.use_kernel,
                 stage_blocks=key)
-            staged_params = dec.stage_params(self.params)
+            staged_params = dec.place_params(self.params)
             step_fn = self.aot.wrap(f"step{key}", jax.jit(dec.build(
                 prestaged_params=True, paged=True)), dispatch="jit")
             probe = self.aot.wrap(f"probe{key}",
@@ -724,7 +726,8 @@ class PagedPipelinedBackend:
     def reset_state(self) -> None:
         cache = self.api.init_paged_cache(*self._shape)
         staged = self.dec._stage_tree(cache[self.seg.name])
-        self.state = (staged, cache["block_tables"], cache["seq_lens"])
+        self.state = (staged, *self.dec.place_replicated(
+            (cache["block_tables"], cache["seq_lens"])))
 
     def step(self, tokens: jnp.ndarray, key) -> jnp.ndarray:
         logits, self.state = self.step_fn(self.staged_params, self.state,
@@ -813,14 +816,14 @@ class PagedPipelinedBackend:
         cfg = self.cfg
         staged, bt, sl = self.state
         b_mb = cfg.num_slots // cfg.num_microbatches
-        x = jnp.zeros((b_mb, 1, self.api.cfg.d_model), L.DEFAULT_DTYPE)
-        mask = jnp.asarray(self.dec._mask)
+        x = np.zeros((b_mb, 1, self.api.cfg.d_model), L.DEFAULT_DTYPE)
+        bt, sl = np.asarray(bt[:b_mb]), np.asarray(sl[:b_mb])
         per_stage = []
         for s in range(cfg.num_stages):
-            blk_p = jax.tree.map(lambda a: a[s],
-                                 self.staged_params[self.seg.name])
-            blk_c = jax.tree.map(lambda a: a[s], staged)
-            args = (blk_p, blk_c, mask[s], x, bt[:b_mb], sl[:b_mb])
+            (blk_p, blk_c), dev = self.dec.stage_shard(
+                (self.staged_params[self.seg.name], staged), s)
+            args = (blk_p, blk_c, *jax.device_put(
+                (self.dec._mask[s], x, bt, sl), dev))
             if not self._probe_warm:
                 jax.block_until_ready(self._probe(*args))
             t0 = time.perf_counter()
@@ -1010,12 +1013,9 @@ class ServingEngine:
         # --- decode backend ----------------------------------------------
         if backend is None:
             backend = "pipelined" if (
-                mesh is not None and cfg.num_stages > 1
-                and pipelined_backend_available()) else "local"
+                mesh is not None and cfg.num_stages > 1) else "local"
         if backend == "pipelined":
-            assert mesh is not None and pipelined_backend_available(), \
-                "pipelined backend needs a mesh and jax.shard_map/set_mesh " \
-                "(jax >= 0.6); use backend='local' on this host"
+            assert mesh is not None, "pipelined backend needs a mesh"
             if self.kv_layout == "paged":
                 self.backend = PagedPipelinedBackend(
                     api, mesh, self.params, cfg, self.stage_blocks,
@@ -1095,11 +1095,6 @@ class ServingEngine:
         assert n % S == 0, \
             f"plan wants {len(planned)} stages, {n} blocks not even over {S}"
         return (n // S,) * S
-
-    def _mesh_ctx(self):
-        if self.mesh is not None and hasattr(jax, "set_mesh"):
-            return jax.set_mesh(self.mesh)
-        return contextlib.nullcontext()
 
     def _emit(self, kind: str, detail: Any = None) -> None:
         ev = EngineEvent(self.steps, kind, detail)
@@ -2212,109 +2207,108 @@ class ServingEngine:
     def step(self) -> List[EngineEvent]:
         self._step_events = []
         self._fault_storm_tick()
-        with self._mesh_ctx():
-            self._admit()
-            # chunked prefill: at most ONE prompt chunk per engine step,
-            # interleaved with the decode tick below so batch-mates keep
-            # emitting tokens while a long prompt fills in
-            self._advance_chunks()
-            # demand paging: back every decoding slot's next write position
-            # with a private page (grow / fork / preempt) BEFORE the step,
-            # so the jitted decode never scatters into a shared page
-            self._grow_active()
-            active = self.scheduler.decoding()
-            if not active:
-                if self.chunking:
-                    # chunk-only step: prefill progressed, nothing decodes
-                    # yet — the engine clock still ticks (wait accounting)
-                    # but the shared timeline must NOT advance
-                    self.steps += 1
-                    self.stalled = False
-                    return self._step_events
-                # head-of-line blocked with nothing running: no completion
-                # can ever free the resource it waits on -> permanently
-                # stalled (callers stop driving; requests stay queued) —
-                # UNLESS a pending mechanism can still unblock the head
-                # (_stall_recoverable: manifest pins the deadlock breaker
-                # can demote/drop, an active storm, in-flight handoff
-                # retries), so the stall is not permanent yet
-                recoverable = self._stall_recoverable()
-                self.stalled = bool(self.scheduler.queue) and not recoverable
-                self.stall_reason = None if not self.scheduler.queue else \
-                    ("recoverable" if recoverable else "permanent")
+        self._admit()
+        # chunked prefill: at most ONE prompt chunk per engine step,
+        # interleaved with the decode tick below so batch-mates keep
+        # emitting tokens while a long prompt fills in
+        self._advance_chunks()
+        # demand paging: back every decoding slot's next write position
+        # with a private page (grow / fork / preempt) BEFORE the step,
+        # so the jitted decode never scatters into a shared page
+        self._grow_active()
+        active = self.scheduler.decoding()
+        if not active:
+            if self.chunking:
+                # chunk-only step: prefill progressed, nothing decodes
+                # yet — the engine clock still ticks (wait accounting)
+                # but the shared timeline must NOT advance
+                self.steps += 1
+                self.stalled = False
                 return self._step_events
-            self.stalled = False
-            self.stall_reason = None
-            self.peak_running = max(self.peak_running, len(active))
-            if self.kv_layout == "timeline":
-                # unreachable: _fits() only admits requests whose worst-case
-                # generation ends inside the horizon
-                assert self.global_len < self.config.max_seq - 1, \
-                    "timeline horizon violated despite admission gating"
+            # head-of-line blocked with nothing running: no completion
+            # can ever free the resource it waits on -> permanently
+            # stalled (callers stop driving; requests stay queued) —
+            # UNLESS a pending mechanism can still unblock the head
+            # (_stall_recoverable: manifest pins the deadlock breaker
+            # can demote/drop, an active storm, in-flight handoff
+            # retries), so the stall is not permanent yet
+            recoverable = self._stall_recoverable()
+            self.stalled = bool(self.scheduler.queue) and not recoverable
+            self.stall_reason = None if not self.scheduler.queue else \
+                ("recoverable" if recoverable else "permanent")
+            return self._step_events
+        self.stalled = False
+        self.stall_reason = None
+        self.peak_running = max(self.peak_running, len(active))
+        if self.kv_layout == "timeline":
+            # unreachable: _fits() only admits requests whose worst-case
+            # generation ends inside the horizon
+            assert self.global_len < self.config.max_seq - 1, \
+                "timeline horizon violated despite admission gating"
 
-            tokens = jnp.asarray(self.pending)[:, None]
-            t0 = time.perf_counter()
-            logits = self.backend.step(tokens, self._key + self.steps)
-            logits = jax.block_until_ready(logits)
-            wall = time.perf_counter() - t0
-            self.steps += 1
-            self.global_len += 1
+        tokens = jnp.asarray(self.pending)[:, None]
+        t0 = time.perf_counter()
+        logits = self.backend.step(tokens, self._key + self.steps)
+        logits = jax.block_until_ready(logits)
+        wall = time.perf_counter() - t0
+        self.steps += 1
+        self.global_len += 1
 
-            # per-slot PRNG keys thread (rid, within-request position), so a
-            # sampled stream is slot/admission/batch-mate independent
-            rids = np.zeros(self.config.num_slots, np.int64)
-            idxs = np.zeros(self.config.num_slots, np.int64)
-            for slot, req in active:
-                rids[slot] = req.rid
-                idxs[slot] = len(req.generated)
-            toks = self.sampler.sample(logits, rids, idxs)
-            for slot, req in active:
-                self.pending[slot] = toks[slot]
-                if self.kv_layout == "paged":
-                    self.slot_len[slot] += 1   # this step's KV write landed
-                    self._maybe_register_decode_page(slot, req)
-                fin = self.scheduler.on_token(slot, int(toks[slot]),
-                                              step=self.steps)
-                if fin is not None:
-                    self._on_finish(fin)
+        # per-slot PRNG keys thread (rid, within-request position), so a
+        # sampled stream is slot/admission/batch-mate independent
+        rids = np.zeros(self.config.num_slots, np.int64)
+        idxs = np.zeros(self.config.num_slots, np.int64)
+        for slot, req in active:
+            rids[slot] = req.rid
+            idxs[slot] = len(req.generated)
+        toks = self.sampler.sample(logits, rids, idxs)
+        for slot, req in active:
+            self.pending[slot] = toks[slot]
+            if self.kv_layout == "paged":
+                self.slot_len[slot] += 1   # this step's KV write landed
+                self._maybe_register_decode_page(slot, req)
+            fin = self.scheduler.on_token(slot, int(toks[slot]),
+                                          step=self.steps)
+            if fin is not None:
+                self._on_finish(fin)
 
-            # telemetry tick → maybe re-plan → maybe swap. Warmup traffic is
-            # synthetic: keep it out of the measured wall clock and the
-            # replanner's EMAs so the first real serve starts clean.
-            if not self._in_warmup:
-                self.telemetry.record_step(wall)
-                if self.steps % self.telemetry.interval == 0:
-                    times = self.backend.stage_times()
-                    if times is None:
-                        shares = self.telemetry.predicted_shares()
-                        times = [wall * s for s in shares]
-                    if times:
-                        self.telemetry.record_stage_times(times)
-                    if self.faults is not None:
-                        self._fault_telemetry_tick()
-                new_spec = self.telemetry.maybe_observe(self.steps)
-                if new_spec is not None:
-                    if self._death_pending:
-                        self.recovery["device_loss_replans"] += 1
-                        self._death_pending = False
-                    if self._stall_stage is not None:
-                        # the replan absorbed the injected straggler;
-                        # clear the factor so the new placement measures
-                        # clean
-                        self.telemetry.inject(self._stall_stage, 1.0)
-                        self._stall_stage = None
-                        self.recovery["stall_replans"] += 1
-                    self._emit("replan",
-                               {"blocks": new_spec.stage_sizes(),
-                                "placement": new_spec.describe()})
-                    if self.config.allow_swap:
-                        self.try_swap(new_spec.stage_sizes())
-                    # adopt the spec only once the executing layout matches
-                    # it (swap applied, or sizes unchanged and only devices
-                    # moved); a skipped swap keeps self.spec on what the
-                    # backend runs
-                    if new_spec.stage_sizes() == self.stage_blocks:
-                        self.spec = new_spec
+        # telemetry tick → maybe re-plan → maybe swap. Warmup traffic is
+        # synthetic: keep it out of the measured wall clock and the
+        # replanner's EMAs so the first real serve starts clean.
+        if not self._in_warmup:
+            self.telemetry.record_step(wall)
+            if self.steps % self.telemetry.interval == 0:
+                times = self.backend.stage_times()
+                if times is None:
+                    shares = self.telemetry.predicted_shares()
+                    times = [wall * s for s in shares]
+                if times:
+                    self.telemetry.record_stage_times(times)
+                if self.faults is not None:
+                    self._fault_telemetry_tick()
+            new_spec = self.telemetry.maybe_observe(self.steps)
+            if new_spec is not None:
+                if self._death_pending:
+                    self.recovery["device_loss_replans"] += 1
+                    self._death_pending = False
+                if self._stall_stage is not None:
+                    # the replan absorbed the injected straggler;
+                    # clear the factor so the new placement measures
+                    # clean
+                    self.telemetry.inject(self._stall_stage, 1.0)
+                    self._stall_stage = None
+                    self.recovery["stall_replans"] += 1
+                self._emit("replan",
+                           {"blocks": new_spec.stage_sizes(),
+                            "placement": new_spec.describe()})
+                if self.config.allow_swap:
+                    self.try_swap(new_spec.stage_sizes())
+                # adopt the spec only once the executing layout matches
+                # it (swap applied, or sizes unchanged and only devices
+                # moved); a skipped swap keeps self.spec on what the
+                # backend runs
+                if new_spec.stage_sizes() == self.stage_blocks:
+                    self.spec = new_spec
         return self._step_events
 
     def _maybe_register_decode_page(self, slot: int, req: Request) -> None:
@@ -2360,8 +2354,7 @@ class ServingEngine:
                 sum(blocks) != self.api.model.segments[0].n:
             self._emit("swap_skipped", {"blocks": blocks})
             return False
-        with self._mesh_ctx():
-            migrated = self.backend.swap(blocks)
+        migrated = self.backend.swap(blocks)
         self._emit("swap", {"from": self.stage_blocks, "to": blocks,
                             "migrated": migrated and
                             self.backend.migrates_cache})
@@ -2401,19 +2394,17 @@ class ServingEngine:
         MONITOR.install()
         self._in_warmup = True
         try:
-            with self._mesh_ctx():
-                self._warm_traffic()
-                self._warm_direct()
-                if self.backend_kind == "pipelined" and \
-                        self.config.allow_swap:
-                    self._warm_layouts()
+            self._warm_traffic()
+            self._warm_direct()
+            if self.backend_kind == "pipelined" and \
+                    self.config.allow_swap:
+                self._warm_layouts()
             self._reset_state()
             if self.kv_layout == "paged":
                 # the reset re-created unsharded device state: re-seed the
                 # (shape, sharding)-keyed dispatch caches for the first
                 # real admissions (state-neutral for paged layouts)
-                with self._mesh_ctx():
-                    self._warm_direct()
+                self._warm_direct()
         finally:
             self._in_warmup = False
         self.aot.freeze()

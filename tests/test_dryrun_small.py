@@ -6,7 +6,7 @@ def test_lower_compile_small_mesh(subproc):
     code = """
 import jax, jax.numpy as jnp
 from repro.configs import get_arch, reduced, ShapeConfig
-from repro.launch.mesh import make_mesh, mesh_context
+from repro.launch.mesh import make_mesh
 from repro.models.api import build_model
 from repro.optim.adamw import AdamWConfig
 from repro.runtime import steps as S
@@ -17,7 +17,7 @@ cfg = reduced(get_arch('llama3.2-1b'))
 api = build_model(cfg, max_seq=64)
 shape = ShapeConfig('t', 64, 4, 'train')
 ab = S.abstract_inputs(api, shape)
-with mesh_context(mesh):
+with jax.set_mesh(mesh):
     step = S.make_train_step(api, mesh, AdamWConfig(), shape)
     lowered = step.lower(ab['params'], ab['opt'], ab['batch'],
                          jax.ShapeDtypeStruct((), jnp.int32))
@@ -38,7 +38,7 @@ def test_decode_cell_small_mesh(subproc):
     code = """
 import jax, jax.numpy as jnp
 from repro.configs import get_arch, reduced, ShapeConfig
-from repro.launch.mesh import make_mesh, mesh_context
+from repro.launch.mesh import make_mesh
 from repro.models.api import build_model
 from repro.runtime import steps as S
 
@@ -47,7 +47,7 @@ cfg = reduced(get_arch('glm4-9b'))
 api = build_model(cfg, max_seq=64)
 shape = ShapeConfig('d', 64, 4, 'decode')
 ab = S.abstract_inputs(api, shape)
-with mesh_context(mesh):
+with jax.set_mesh(mesh):
     step = S.make_decode_step(api, mesh, shape)
     compiled = step.lower(ab['params'], ab['cache'], ab['batch']).compile()
 print('OK')

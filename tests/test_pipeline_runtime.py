@@ -1,11 +1,6 @@
 """Pipelined serve (shard_map over pod) vs sequential decode — multi-device,
 run in subprocesses so the main process keeps 1 device."""
-import jax
 import pytest
-
-pytestmark = pytest.mark.skipif(
-    not (hasattr(jax, "shard_map") and hasattr(jax, "set_mesh")),
-    reason="needs jax.shard_map/jax.set_mesh (jax >= 0.6)")
 
 
 PIPE_CODE = """

@@ -1,7 +1,7 @@
 """Serving engine: scheduler invariants, telemetry-driven re-planning,
 stage-layout cache migration, and request isolation under continuous
-batching. The shard_map pipelined-backend paths run in subprocesses and
-skip on jax < 0.6 (same gate as test_pipeline_runtime.py)."""
+batching. The shard_map pipelined-backend paths run in subprocesses on
+virtual devices (as test_pipeline_runtime.py does)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,8 +9,6 @@ import pytest
 
 from repro.configs import get_arch, reduced
 from repro.serving.scheduler import DONE, SlotScheduler
-
-NEW_JAX = hasattr(jax, "shard_map") and hasattr(jax, "set_mesh")
 
 
 # ---------------------------------------------------------------------------
@@ -370,11 +368,8 @@ def test_profiles_calibrate_from_hlo():
 
 
 # ---------------------------------------------------------------------------
-# Pipelined backend (subprocess; CI / jax >= 0.6 only)
+# Pipelined backend (subprocess on virtual devices)
 # ---------------------------------------------------------------------------
-pipelined = pytest.mark.skipif(not NEW_JAX,
-                               reason="needs jax.shard_map/jax.set_mesh")
-
 ENGINE_PIPE_CODE = """
 import jax, jax.numpy as jnp, numpy as np
 import repro.models.layers as L
@@ -411,7 +406,6 @@ def run(backend, inject, kv_layout='paged'):
 """
 
 
-@pipelined
 def test_engine_pipelined_matches_local(subproc):
     body = """
 e_pipe, toks_pipe = run('pipelined', inject=False)
@@ -427,7 +421,6 @@ print('OK')
     assert "OK" in out
 
 
-@pipelined
 def test_engine_pipelined_live_swap_token_exact(subproc):
     """The acceptance demo in-test: straggler -> re-plan -> restage_cache
     migration; streams identical to an un-swapped pipelined run."""

@@ -367,7 +367,6 @@ def test_decode_cow_registers_generated_pages(setup):
 def test_warmed_engine_swap_traffic_zero_compiles(setup):
     """Warmup covers the sealed gather/scatter transfer path; a tight pool
     then drives real swap-outs and swap-ins with ZERO new XLA compiles."""
-    from repro.serving import MONITOR
     cfg, api, params = setup
     eng = _engine(api, params, num_slots=3, num_microbatches=1,
                   request_capacity=24, num_pages=8, page_policy="demand",
@@ -381,22 +380,13 @@ def test_warmed_engine_swap_traffic_zero_compiles(setup):
     assert st["swap_outs"] > 0 and st["swap_ins"] > 0
     assert st["warmed"] and st["warmup_s"] > 0
     assert st["compile_stalls"] == [], st["compile_stalls"]
-    assert st["post_warmup_compiles"] in (None, 0), \
-        st["post_warmup_compiles"]
-    if not MONITOR.available:            # pragma: no cover - jax internals
-        pytest.skip("compile monitor unavailable on this jax version")
+    assert st["post_warmup_compiles"] == 0, st["post_warmup_compiles"]
 
 
 # ---------------------------------------------------------------------------
 # Pipelined backends: restage memoization + staged swap transfer
-# (subprocess; CI / jax >= 0.6 only)
+# (subprocess on virtual devices)
 # ---------------------------------------------------------------------------
-pipelined = pytest.mark.skipif(
-    not (hasattr(jax, "shard_map") and hasattr(jax, "set_mesh")),
-    reason="needs jax.shard_map/jax.set_mesh (jax >= 0.6)")
-
-
-@pipelined
 def test_pipelined_restage_pair_memoized_no_stall(subproc):
     """PR 7 layout-tour gap, closed: a chain of swaps between two
     NON-planned layouts lazily AOT-warms each (from, to) restage pair once
@@ -434,11 +424,11 @@ def test_pipelined_restage_pair_memoized_no_stall(subproc):
         # stall ledger
         assert eng.try_swap(a) and eng.try_swap(b) and eng.try_swap(a)
         assert eng.aot.post_freeze_stalls == []
-        c1 = MONITOR.backend_compiles if MONITOR.available else None
+        c1 = MONITOR.backend_compiles
         # the SAME pairs again must be compile-free (memoized dispatch)
         assert eng.try_swap(b) and eng.try_swap(a) and eng.try_swap(b)
-        c2 = MONITOR.backend_compiles if MONITOR.available else None
-        assert c1 is None or c2 == c1, (c1, c2)
+        c2 = MONITOR.backend_compiles
+        assert c2 == c1, (c1, c2)
         assert eng.aot.post_freeze_stalls == []
         assert ((a, b) in eng.backend._restage
                 and (b, a) in eng.backend._restage)
@@ -450,7 +440,6 @@ def test_pipelined_restage_pair_memoized_no_stall(subproc):
     """, devices=4)
 
 
-@pipelined
 def test_pipelined_swap_preemption_token_exact(subproc):
     """The sharded staged page pools expose the same sealed gather/scatter
     primitives: swap preemption on the pipelined backend resumes with

@@ -6,10 +6,6 @@ import jax
 import numpy as np
 import pytest
 
-pytestmark = pytest.mark.skipif(
-    not hasattr(jax, "set_mesh"),
-    reason="needs jax.set_mesh (jax >= 0.6)")
-
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs import get_arch, reduced, ShapeConfig
 from repro.data.tokens import SyntheticTokenStream
