@@ -271,9 +271,11 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens, *,
     """
     from repro.kernels import ops as KO
     B, _, H, D = q.shape
-    out = KO.paged_attention(q.reshape(B, H, D), k_pages, v_pages,
-                             block_tables, seq_lens, use_kernel=use_kernel)
-    return out.reshape(B, 1, H, D).astype(q.dtype)
+    with jax.named_scope("paged_attention"):
+        out = KO.paged_attention(q.reshape(B, H, D), k_pages, v_pages,
+                                 block_tables, seq_lens,
+                                 use_kernel=use_kernel)
+        return out.reshape(B, 1, H, D).astype(q.dtype)
 
 
 def paged_write(k_pages, v_pages, k_new, v_new, block_tables, seq_lens):
@@ -291,14 +293,15 @@ def paged_write(k_pages, v_pages, k_new, v_new, block_tables, seq_lens):
     named here as a write target, so this scatter only ever lands on pages
     with exactly one owner."""
     N, _, Pg, _ = k_pages.shape
-    page = jnp.take_along_axis(
-        block_tables, (seq_lens // Pg)[:, None], axis=1)[:, 0]     # [B]
-    page = jnp.where(page == 0, N, page)    # null target -> out of range
-    off = seq_lens % Pg
-    # advanced indices split by the head slice put the batch dim first
-    k_pages = k_pages.at[page, :, off].set(k_new, mode="drop")
-    v_pages = v_pages.at[page, :, off].set(v_new, mode="drop")
-    return k_pages, v_pages
+    with jax.named_scope("kv_write"):
+        page = jnp.take_along_axis(
+            block_tables, (seq_lens // Pg)[:, None], axis=1)[:, 0]  # [B]
+        page = jnp.where(page == 0, N, page)  # null target -> out of range
+        off = seq_lens % Pg
+        # advanced indices split by the head slice put the batch dim first
+        k_pages = k_pages.at[page, :, off].set(k_new, mode="drop")
+        v_pages = v_pages.at[page, :, off].set(v_new, mode="drop")
+        return k_pages, v_pages
 
 
 def chunk_prefill_attention(q, k, v, k_pages, v_pages, block_tables,
@@ -357,9 +360,10 @@ def paged_write_chunk(k_pages, v_pages, k_new, v_new, pages, offs):
     (num_pages) in ``pages`` and are dropped — the same immutability
     contract as ``paged_write`` (page 0 and shared pages are never
     touched). k_new, v_new: [C, KVH, D]; pages, offs: [C] int32."""
-    k_pages = k_pages.at[pages, :, offs].set(k_new, mode="drop")
-    v_pages = v_pages.at[pages, :, offs].set(v_new, mode="drop")
-    return k_pages, v_pages
+    with jax.named_scope("kv_write"):
+        k_pages = k_pages.at[pages, :, offs].set(k_new, mode="drop")
+        v_pages = v_pages.at[pages, :, offs].set(v_new, mode="drop")
+        return k_pages, v_pages
 
 
 def roll_into_window(kv_hd, total_len: int, window: int):

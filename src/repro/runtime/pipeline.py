@@ -301,7 +301,7 @@ class PipelinedDecoder:
                 h, _ = self._stage_run(first(blk_params), rows, blk_mask, x,
                                        cache_len)
                 return h
-        return jax.jit(probe)
+        return probe
 
     # -- the step -------------------------------------------------------------
     def build(self, prestaged_params: bool = False,

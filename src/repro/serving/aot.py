@@ -34,7 +34,9 @@ at warmup. This module is the machinery behind ``ServingEngine.warmup()``
   in ``ServingEngine.stats()["compile_stalls"]``, and fatal in tests/CI.
 * ``AotRegistry`` — the per-engine collection of AotFns plus the
   freeze-time monitor baseline (counters are process-global, so each
-  engine snapshots its own zero point).
+  engine snapshots its own zero point). ``wrap(name, fn)`` jits ``fn``
+  under its ledger name, so the XLA module (and a profiler trace) names
+  the program ``jit_<name>``.
 """
 from __future__ import annotations
 
@@ -82,6 +84,16 @@ def _sig_of(args) -> Tuple:
         else:                            # python scalar -> weak type
             avals.append(((), type(x).__name__, True))
     return treedef, tuple(avals)
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn`` under the function name ``name``, which jax gives the XLA
+    module it lowers to (``jit_<name>``)."""
+    def named(*args, **kwargs):
+        return fn(*args, **kwargs)
+    named.__name__ = named.__qualname__ = name
+    named.__wrapped__ = fn              # jax reads the signature through it
+    return named
 
 
 @dataclasses.dataclass
@@ -148,7 +160,8 @@ class AotRegistry:
 
     def wrap(self, name: str, fn: Callable,
              dispatch: str = "compiled") -> AotFn:
-        f = AotFn(name, fn, self, dispatch=dispatch)
+        """Manage ``fn`` (a plain function, jitted here) as ``name``."""
+        f = AotFn(name, jax.jit(_named(fn, name)), self, dispatch=dispatch)
         self.fns[name] = f
         return f
 

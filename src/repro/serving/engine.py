@@ -151,12 +151,11 @@ class EngineConfig:
     #                                     per engine step between decode
     #                                     ticks (0 = whole-prompt admission)
     # host-history ring-buffer caps: events / finished transcripts /
-    # step-time samples / admission latencies keep only this many entries
-    # (lifetime aggregates in stats() stay exact), so a week-long serve
-    # holds constant host memory
+    # admission latencies keep only this many entries (lifetime aggregates
+    # in stats() stay exact), so a week-long serve holds constant host
+    # memory
     events_cap: int = 4096
     finished_cap: int = 4096
-    step_times_cap: int = 4096
     admission_cap: int = 4096
     # chaos-injection fault plane (serving/faults.py): a FaultConfig, or
     # None to serve fault-free. Injection counters surface in
@@ -190,7 +189,7 @@ class LocalDecodeBackend:
         # executables is the zero-recompile path (serving/aot.py). The slot
         # index is traced (not a static eager index) so one compiled insert
         # covers every slot.
-        self._step = self.aot.wrap("decode_step", jax.jit(api.decode_fn))
+        self._step = self.aot.wrap("decode_step", api.decode_fn)
 
         def insert(body, start, upd_body, upd_start, b):
             body = jax.tree.map(
@@ -198,7 +197,7 @@ class LocalDecodeBackend:
                     g, s, b, axis=1), body, upd_body)
             return body, jax.lax.dynamic_update_slice(start, upd_start, (b,))
 
-        self._insert = self.aot.wrap("insert", jax.jit(insert))
+        self._insert = self.aot.wrap("insert", insert)
 
     def reset_state(self) -> None:
         cfg = self.cfg
@@ -253,8 +252,8 @@ class PipelinedDecodeBackend:
         #                                         memoized jitted restage
         self._build(stage_blocks)
         self.reset_state()
-        self._insert = self.aot.wrap("insert", jax.jit(
-            self._insert_impl), dispatch="jit")
+        self._insert = self.aot.wrap("insert", self._insert_impl,
+                                     dispatch="jit")
 
     def _restage_state(self, old_dec, old_key) -> None:
         """Migrate ``self.state`` from ``old_dec``'s layout to the current
@@ -269,7 +268,7 @@ class PipelinedDecodeBackend:
             new_dec = self.dec
             fn = self.aot.wrap(
                 f"restage{pair[0]}->{pair[1]}",
-                jax.jit(lambda st: old_dec.restage_cache(st, new_dec)),
+                lambda st: old_dec.restage_cache(st, new_dec),
                 dispatch="jit")
             self._restage[pair] = fn
             self.state = fn.warm(self.state)
@@ -296,9 +295,9 @@ class PipelinedDecodeBackend:
             staged_params = dec.place_params(self.params)
             # shard_map state changes sharding between the first and
             # steady-state call -> "jit" dispatch (serving/aot.py)
-            step_fn = self.aot.wrap(f"step{key}", jax.jit(dec.build(
+            step_fn = self.aot.wrap(f"step{key}", dec.build(
                 prestaged_params=True, prestaged_cache=True,
-                per_slot_start=True)), dispatch="jit")
+                per_slot_start=True), dispatch="jit")
             probe = self.aot.wrap(f"probe{key}", dec.build_stage_probe(),
                                   dispatch="jit")
             hit = self._layouts[key] = (dec, staged_params, step_fn, probe)
@@ -392,9 +391,8 @@ class PagedLocalBackend:
         self.reset_state()
         # use_kernel is bound statically at jit time: fused Pallas paged
         # attention on TPU, jnp page-gather otherwise
-        self._step = self.aot.wrap("decode_step", jax.jit(
-            functools.partial(api.decode_paged_fn,
-                              use_kernel=cfg.use_kernel)))
+        self._step = self.aot.wrap("decode_step", functools.partial(
+            api.decode_paged_fn, use_kernel=cfg.use_kernel))
         seg_name = self.seg.name
 
         def insert(cache, kk, vv, pages, offs, slot, bt_row, seq_len):
@@ -487,14 +485,14 @@ class PagedLocalBackend:
             out[seg_name] = (restored(k_pool, ck, 0), restored(v_pool, cv, 1))
             return out
 
-        self._insert = self.aot.wrap("insert", jax.jit(insert))
-        self._clear = self.aot.wrap("clear_slot", jax.jit(clear))
-        self._set_bt = self.aot.wrap("set_table_entry", jax.jit(set_bt))
-        self._copy_pg = self.aot.wrap("copy_page", jax.jit(copy_pg))
-        self._chunk = self.aot.wrap("prefill_chunk", jax.jit(chunk))
-        self._commit = self.aot.wrap("commit_slot", jax.jit(commit))
-        self._gather = self.aot.wrap("gather_pages", jax.jit(gather))
-        self._scatter = self.aot.wrap("scatter_pages", jax.jit(scatter))
+        self._insert = self.aot.wrap("insert", insert)
+        self._clear = self.aot.wrap("clear_slot", clear)
+        self._set_bt = self.aot.wrap("set_table_entry", set_bt)
+        self._copy_pg = self.aot.wrap("copy_page", copy_pg)
+        self._chunk = self.aot.wrap("prefill_chunk", chunk)
+        self._commit = self.aot.wrap("commit_slot", commit)
+        self._gather = self.aot.wrap("gather_pages", gather)
+        self._scatter = self.aot.wrap("scatter_pages", scatter)
 
     def reset_state(self) -> None:
         self.cache = self.api.init_paged_cache(*self._shape)
@@ -607,11 +605,11 @@ class PagedPipelinedBackend:
             return bt.at[slot].set(bt_row), sl.at[slot].set(seq_len)
 
         wrap = functools.partial(self.aot.wrap, dispatch="jit")
-        self._insert = wrap("insert", jax.jit(insert))
-        self._clear = wrap("clear_slot", jax.jit(clear))
-        self._set_bt = wrap("set_table_entry", jax.jit(set_bt))
-        self._copy_pg = wrap("copy_page", jax.jit(copy_pg))
-        self._commit = wrap("commit_slot", jax.jit(commit))
+        self._insert = wrap("insert", insert)
+        self._clear = wrap("clear_slot", clear)
+        self._set_bt = wrap("set_table_entry", set_bt)
+        self._copy_pg = wrap("copy_page", copy_pg)
+        self._commit = wrap("commit_slot", commit)
 
     def _make_chunk(self, dec):
         """Chunked prefill against the STAGED pools: unstage -> run the
@@ -704,18 +702,17 @@ class PagedPipelinedBackend:
                 seal_boundary=cfg.seal_boundary, use_kernel=cfg.use_kernel,
                 stage_blocks=key)
             staged_params = dec.place_params(self.params)
-            step_fn = self.aot.wrap(f"step{key}", jax.jit(dec.build(
-                prestaged_params=True, paged=True)), dispatch="jit")
+            step_fn = self.aot.wrap(f"step{key}", dec.build(
+                prestaged_params=True, paged=True), dispatch="jit")
             probe = self.aot.wrap(f"probe{key}",
                                   dec.build_stage_probe(paged=True),
                                   dispatch="jit")
-            chunk_fn = self.aot.wrap(f"chunk{key}",
-                                     jax.jit(self._make_chunk(dec)),
+            chunk_fn = self.aot.wrap(f"chunk{key}", self._make_chunk(dec),
                                      dispatch="jit")
             g_fn, s_fn = self._make_swapio(dec)
-            gather_fn = self.aot.wrap(f"gather_pages{key}", jax.jit(g_fn),
+            gather_fn = self.aot.wrap(f"gather_pages{key}", g_fn,
                                       dispatch="jit")
-            scatter_fn = self.aot.wrap(f"scatter_pages{key}", jax.jit(s_fn),
+            scatter_fn = self.aot.wrap(f"scatter_pages{key}", s_fn,
                                        dispatch="jit")
             hit = self._layouts[key] = (dec, staged_params, step_fn, probe,
                                         chunk_fn, gather_fn, scatter_fn)
@@ -795,7 +792,7 @@ class PagedPipelinedBackend:
             new_dec = self.dec
             fn = self.aot.wrap(
                 f"restage{pair[0]}->{pair[1]}",
-                jax.jit(lambda st: old_dec.restage_cache(st, new_dec)),
+                lambda st: old_dec.restage_cache(st, new_dec),
                 dispatch="jit")
             self._restage[pair] = fn
             self.state = fn.warm(self.state)
@@ -926,8 +923,7 @@ class ServingEngine:
             self.replanner,
             monitor=HeartbeatMonitor(self.rm,
                                      timeout_s=cfg.heartbeat_timeout_s),
-            interval=cfg.telemetry_interval,
-            step_times_cap=cfg.step_times_cap)
+            interval=cfg.telemetry_interval)
         # per-engine AOT compile ledger; every jitted serving function is
         # registered here so warmup() can compile the full shape inventory
         # and stats() can report post-warmup compile stalls
@@ -1054,11 +1050,11 @@ class ServingEngine:
         self.events: Deque[EngineEvent] = deque(maxlen=cfg.events_cap)
         self._step_events: List[EngineEvent] = []
         disp = "jit" if backend == "pipelined" else "compiled"
-        self._prefill = self.aot.wrap("prefill_token",
-                                      jax.jit(api.decode_fn), dispatch=disp)
+        self._prefill = self.aot.wrap("prefill_token", api.decode_fn,
+                                      dispatch=disp)
         if self.kv_layout == "paged":
             self._prefill_at = self.aot.wrap(
-                "prefill_bucket", jax.jit(api.prefill_at_fn), dispatch=disp)
+                "prefill_bucket", api.prefill_at_fn, dispatch=disp)
         # packed prefill: K prompts share one bucketed call, logits read
         # per-row at each prompt's own last position (satellite of the
         # disaggregated prefill role, but usable monolithically too)
@@ -1067,8 +1063,7 @@ class ServingEngine:
         if (cfg.prefill_pack > 1 and self.kv_layout == "paged"
                 and cfg.batched_prefill):
             self._prefill_at_packed = self.aot.wrap(
-                "prefill_packed", jax.jit(api.prefill_packed_fn),
-                dispatch=disp)
+                "prefill_packed", api.prefill_packed_fn, dispatch=disp)
         self.packed_admissions = 0
         self.packed_prefills = 0
         self._key = jnp.uint32(0xC0FFEE)
@@ -1275,7 +1270,9 @@ class ServingEngine:
         # a resumed request's first sample continues its keystream at
         # len(generated) — at temperature 0 this is the same argmax the
         # interrupted decode step would have taken (teacher forcing)
-        first = self.sampler.sample_one(logits, req.rid, len(req.generated))
+        with self.telemetry.span("serve.device_wait"):
+            first = self.sampler.sample_one(logits, req.rid,
+                                            len(req.generated))
         self.pending[slot] = first
         detail["ms"] = (time.perf_counter() - t0) * 1e3
         self.admission_ms.append(detail["ms"])
@@ -1429,10 +1426,11 @@ class ServingEngine:
             return
         slot = min(self.chunking, key=lambda s: self.chunking[s].req.rid)
         cs = self.chunking[slot]
-        self._run_chunk(slot, cs)
-        # _run_chunk may have preempted the slot mid-acquisition
-        if slot in self.chunking and cs.pos == len(cs.tokens):
-            self._finish_chunked(slot, cs)
+        with self.telemetry.span("serve.chunk", rid=cs.req.rid):
+            self._run_chunk(slot, cs)
+            # _run_chunk may have preempted the slot mid-acquisition
+            if slot in self.chunking and cs.pos == len(cs.tokens):
+                self._finish_chunked(slot, cs)
 
     def _run_chunk(self, slot: int, cs: _ChunkState) -> None:
         cfg = self.config
@@ -1504,8 +1502,9 @@ class ServingEngine:
         self.slot_len[slot] = P
         del self.chunking[slot]
         self.scheduler.mark_running(slot)
-        first = self.sampler.sample_one(cs.logits, req.rid,
-                                        len(req.generated))
+        with self.telemetry.span("serve.device_wait"):
+            first = self.sampler.sample_one(cs.logits, req.rid,
+                                            len(req.generated))
         self.pending[slot] = first
         ms = (time.perf_counter() - cs.t0) * 1e3
         self.admission_ms.append(ms)
@@ -2109,11 +2108,13 @@ class ServingEngine:
                 return
             self._blocked_rid = None
             if self._prefill_at_packed is not None and self._packable(nxt):
-                self._admit_packed()
+                with self.telemetry.span("serve.admit", rid=nxt.rid):
+                    self._admit_packed()
                 continue
             hit = self.scheduler.admit_next(step=self.steps)
             assert hit is not None
-            self._prefill_slot(*hit)
+            with self.telemetry.span("serve.admit", rid=hit[1].rid):
+                self._prefill_slot(*hit)
 
     def _packable(self, req: Request) -> bool:
         """Can ``req`` join a packed prefill group? Plain one-shot paged
@@ -2186,8 +2187,9 @@ class ServingEngine:
                 slot, (kk_all[:, b], vv_all[:, b]),
                 jnp.asarray(pages_vec.astype(np.int32)),
                 jnp.asarray(offs_vec), jnp.asarray(bt_row), P)
-            first = self.sampler.sample_one(logits[b:b + 1], req.rid,
-                                            len(req.generated))
+            with self.telemetry.span("serve.device_wait"):
+                first = self.sampler.sample_one(logits[b:b + 1], req.rid,
+                                                len(req.generated))
             self.pending[slot] = first
             ms = (time.perf_counter() - t0) * 1e3
             self.admission_ms.append(ms)
@@ -2205,6 +2207,10 @@ class ServingEngine:
 
     # -- one decode step ---------------------------------------------------
     def step(self) -> List[EngineEvent]:
+        with self.telemetry.span("serve.step"):
+            return self._step()
+
+    def _step(self) -> List[EngineEvent]:
         self._step_events = []
         self._fault_storm_tick()
         self._admit()
@@ -2215,7 +2221,8 @@ class ServingEngine:
         # demand paging: back every decoding slot's next write position
         # with a private page (grow / fork / preempt) BEFORE the step,
         # so the jitted decode never scatters into a shared page
-        self._grow_active()
+        with self.telemetry.span("serve.grow"):
+            self._grow_active()
         active = self.scheduler.decoding()
         if not active:
             if self.chunking:
@@ -2246,69 +2253,74 @@ class ServingEngine:
             assert self.global_len < self.config.max_seq - 1, \
                 "timeline horizon violated despite admission gating"
 
-        tokens = jnp.asarray(self.pending)[:, None]
-        t0 = time.perf_counter()
-        logits = self.backend.step(tokens, self._key + self.steps)
-        logits = jax.block_until_ready(logits)
+        with self.telemetry.span("serve.dispatch"):
+            tokens = jnp.asarray(self.pending)[:, None]
+            t0 = time.perf_counter()
+            logits = self.backend.step(tokens, self._key + self.steps)
+        with self.telemetry.span("serve.device_wait"):
+            logits = jax.block_until_ready(logits)
         wall = time.perf_counter() - t0
         self.steps += 1
         self.global_len += 1
 
         # per-slot PRNG keys thread (rid, within-request position), so a
         # sampled stream is slot/admission/batch-mate independent
-        rids = np.zeros(self.config.num_slots, np.int64)
-        idxs = np.zeros(self.config.num_slots, np.int64)
-        for slot, req in active:
-            rids[slot] = req.rid
-            idxs[slot] = len(req.generated)
-        toks = self.sampler.sample(logits, rids, idxs)
-        for slot, req in active:
-            self.pending[slot] = toks[slot]
-            if self.kv_layout == "paged":
-                self.slot_len[slot] += 1   # this step's KV write landed
-                self._maybe_register_decode_page(slot, req)
-            fin = self.scheduler.on_token(slot, int(toks[slot]),
-                                          step=self.steps)
-            if fin is not None:
-                self._on_finish(fin)
+        with self.telemetry.span("serve.sample"):
+            rids = np.zeros(self.config.num_slots, np.int64)
+            idxs = np.zeros(self.config.num_slots, np.int64)
+            for slot, req in active:
+                rids[slot] = req.rid
+                idxs[slot] = len(req.generated)
+            toks = self.sampler.sample(logits, rids, idxs)
+        with self.telemetry.span("serve.emit"):
+            for slot, req in active:
+                self.pending[slot] = toks[slot]
+                if self.kv_layout == "paged":
+                    self.slot_len[slot] += 1   # this step's KV write landed
+                    self._maybe_register_decode_page(slot, req)
+                fin = self.scheduler.on_token(slot, int(toks[slot]),
+                                              step=self.steps)
+                if fin is not None:
+                    self._on_finish(fin)
 
         # telemetry tick → maybe re-plan → maybe swap. Warmup traffic is
         # synthetic: keep it out of the measured wall clock and the
         # replanner's EMAs so the first real serve starts clean.
-        if not self._in_warmup:
-            self.telemetry.record_step(wall)
-            if self.steps % self.telemetry.interval == 0:
-                times = self.backend.stage_times()
-                if times is None:
-                    shares = self.telemetry.predicted_shares()
-                    times = [wall * s for s in shares]
-                if times:
-                    self.telemetry.record_stage_times(times)
-                if self.faults is not None:
-                    self._fault_telemetry_tick()
-            new_spec = self.telemetry.maybe_observe(self.steps)
-            if new_spec is not None:
-                if self._death_pending:
-                    self.recovery["device_loss_replans"] += 1
-                    self._death_pending = False
-                if self._stall_stage is not None:
-                    # the replan absorbed the injected straggler;
-                    # clear the factor so the new placement measures
-                    # clean
-                    self.telemetry.inject(self._stall_stage, 1.0)
-                    self._stall_stage = None
-                    self.recovery["stall_replans"] += 1
-                self._emit("replan",
-                           {"blocks": new_spec.stage_sizes(),
-                            "placement": new_spec.describe()})
-                if self.config.allow_swap:
-                    self.try_swap(new_spec.stage_sizes())
-                # adopt the spec only once the executing layout matches
-                # it (swap applied, or sizes unchanged and only devices
-                # moved); a skipped swap keeps self.spec on what the
-                # backend runs
-                if new_spec.stage_sizes() == self.stage_blocks:
-                    self.spec = new_spec
+        with self.telemetry.span("serve.telemetry"):
+            if not self._in_warmup:
+                self.telemetry.record_step(wall)
+                if self.steps % self.telemetry.interval == 0:
+                    times = self.backend.stage_times()
+                    if times is None:
+                        shares = self.telemetry.predicted_shares()
+                        times = [wall * s for s in shares]
+                    if times:
+                        self.telemetry.record_stage_times(times)
+                    if self.faults is not None:
+                        self._fault_telemetry_tick()
+                new_spec = self.telemetry.maybe_observe(self.steps)
+                if new_spec is not None:
+                    if self._death_pending:
+                        self.recovery["device_loss_replans"] += 1
+                        self._death_pending = False
+                    if self._stall_stage is not None:
+                        # the replan absorbed the injected straggler;
+                        # clear the factor so the new placement measures
+                        # clean
+                        self.telemetry.inject(self._stall_stage, 1.0)
+                        self._stall_stage = None
+                        self.recovery["stall_replans"] += 1
+                    self._emit("replan",
+                               {"blocks": new_spec.stage_sizes(),
+                                "placement": new_spec.describe()})
+                    if self.config.allow_swap:
+                        self.try_swap(new_spec.stage_sizes())
+                    # adopt the spec only once the executing layout matches
+                    # it (swap applied, or sizes unchanged and only devices
+                    # moved); a skipped swap keeps self.spec on what the
+                    # backend runs
+                    if new_spec.stage_sizes() == self.stage_blocks:
+                        self.spec = new_spec
         return self._step_events
 
     def _maybe_register_decode_page(self, slot: int, req: Request) -> None:
@@ -2715,6 +2727,7 @@ class ServingEngine:
     def stats(self) -> Dict[str, Any]:
         out = dict(self.scheduler.stats())
         wall = self.telemetry.wall_s
+        step_s = self.telemetry.span_s["serve.step"]
         out.update({
             "steps": self.steps,
             "swaps": self.swaps,
@@ -2726,7 +2739,8 @@ class ServingEngine:
             "stage_blocks": self.stage_blocks,
             "placement": self.spec.describe(),
             "decode_wall_s": wall,
-            "tok_per_s": (out["tokens_out"] / wall) if wall > 0 else 0.0,
+            "tok_per_s": (out["tokens_out"] / step_s) if step_s > 0
+            else 0.0,
             "prefill_calls": self.prefill_calls,
             "admissions": self.admissions,
             "warmed": self.warmed,

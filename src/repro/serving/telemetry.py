@@ -20,15 +20,65 @@ predicted share.
 normalization — the straggler-injection hook used by tests, the serve CLI
 and the throughput benchmark to exercise the live re-plan path on
 homogeneous hardware.
+
+Program spans: ``span(name, **meta)`` opens a
+``jax.profiler.TraceAnnotation`` (so a profiler trace shows the span on its
+host plane, on the device planes' clock) and adds the span's host-clock
+duration to a running total and count per name. The totals are always on
+and constant in size; readers take deltas, like ``wall_s``. Python's
+garbage-collection pauses are recorded the same way, as ``py.gc``, through
+one process-wide ``gc.callbacks`` hook.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-from collections import deque
-from typing import Deque, Dict, Optional, Sequence
+import gc
+import time
+from typing import Dict, Iterator, List, Optional, Sequence, Tuple
+
+import jax
 
 from repro.core.planner import PlacementSpec
 from repro.runtime.ft import HeartbeatMonitor, OnlineReplanner
+
+#: the spans ``ServingEngine.step`` opens, outermost first
+SPANS = ("serve.step", "serve.admit", "serve.chunk", "serve.grow",
+         "serve.dispatch", "serve.device_wait", "serve.sample", "serve.emit",
+         "serve.telemetry")
+GC_SPAN = "py.gc"
+
+
+class _GcPauses:
+    """Garbage-collection pauses as ``py.gc`` spans with process totals.
+    Collection is process-wide, so there is one hook per process."""
+
+    def __init__(self):
+        self.seconds = 0.0
+        self.count = 0
+        self._open = None
+        self.installed = False
+
+    def install(self) -> None:
+        if not self.installed:
+            gc.callbacks.append(self._on_gc)
+            self.installed = True
+
+    def _on_gc(self, phase: str, info: Dict) -> None:
+        if phase == "start":
+            ann = jax.profiler.TraceAnnotation(
+                GC_SPAN, generation=info["generation"])
+            ann.__enter__()
+            self._open = (ann, time.perf_counter())
+        elif self._open is not None:
+            ann, t0 = self._open
+            self._open = None
+            ann.__exit__(None, None, None)
+            self.seconds += time.perf_counter() - t0
+            self.count += 1
+
+
+GC_PAUSES = _GcPauses()
 
 
 @dataclasses.dataclass
@@ -39,16 +89,17 @@ class StageTelemetry:
     ema: float = 0.5                    # new-sample weight
     _stage_ema: Dict[int, float] = dataclasses.field(default_factory=dict)
     _inject: Dict[int, float] = dataclasses.field(default_factory=dict)
-    # recent window only (ring buffer) — wall_s/steps_recorded keep lifetime
-    # totals exact so a week-long serve doesn't grow host memory per step
-    step_times_cap: Optional[int] = None
-    step_times: Deque[float] = dataclasses.field(default_factory=deque)
     wall_s: float = 0.0
     steps_recorded: int = 0
     observations: int = 0
+    span_s: Dict[str, float] = dataclasses.field(
+        init=False, default_factory=lambda: dict.fromkeys(SPANS, 0.0))
+    span_n: Dict[str, int] = dataclasses.field(
+        init=False, default_factory=lambda: dict.fromkeys(SPANS, 0))
 
     def __post_init__(self):
-        self.step_times = deque(self.step_times, maxlen=self.step_times_cap)
+        GC_PAUSES.install()
+        self._gc_base = (GC_PAUSES.seconds, GC_PAUSES.count)
 
     # -- fault injection ----------------------------------------------------
     def inject(self, stage: int, factor: float) -> None:
@@ -61,16 +112,40 @@ class StageTelemetry:
 
     # -- measurement --------------------------------------------------------
     def record_step(self, wall_dt: float) -> None:
-        self.step_times.append(wall_dt)
         self.wall_s += wall_dt
         self.steps_recorded += 1
 
+    @contextlib.contextmanager
+    def span(self, name: str, **meta) -> Iterator[None]:
+        """A program span: a profiler ``TraceAnnotation`` carrying
+        ``meta``, whose host-clock duration adds to ``name``'s totals. It
+        adds no device work and no device sync."""
+        t0 = time.perf_counter()
+        try:
+            with jax.profiler.TraceAnnotation(name, **meta):
+                yield
+        finally:
+            self.span_s[name] = self.span_s.get(name, 0.0) \
+                + time.perf_counter() - t0
+            self.span_n[name] = self.span_n.get(name, 0) + 1
+
+    def span_totals(self) -> Dict[str, Tuple[float, int]]:
+        """Seconds and count of every span name since the last reset,
+        ``py.gc`` included (pauses anywhere in the process)."""
+        out = {n: (self.span_s[n], self.span_n[n]) for n in self.span_s}
+        out[GC_SPAN] = (GC_PAUSES.seconds - self._gc_base[0],
+                        GC_PAUSES.count - self._gc_base[1])
+        return out
+
     def reset_measurements(self) -> None:
-        """Zero the wall-time accounting (warmup reset / benchmark phase
-        boundaries) without touching replanner state or stage EMAs."""
-        self.step_times.clear()
+        """Zero the wall-time and span accounting (warmup reset / benchmark
+        phase boundaries) without touching replanner state or stage
+        EMAs."""
         self.wall_s = 0.0
         self.steps_recorded = 0
+        self.span_s = dict.fromkeys(SPANS, 0.0)
+        self.span_n = dict.fromkeys(SPANS, 0)
+        self._gc_base = (GC_PAUSES.seconds, GC_PAUSES.count)
 
     def record_stage_times(self, times: Sequence[float]) -> None:
         """Fold one per-stage probe (host wall seconds, stage order) into the

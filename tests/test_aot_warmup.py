@@ -114,7 +114,7 @@ def test_sig_of_discriminates_shapes_dtypes_and_scalars():
 
 def test_aotfn_warm_then_call_no_stall():
     reg = AotRegistry()
-    f = reg.wrap("double", jax.jit(lambda x: x * 2))
+    f = reg.wrap("double", lambda x: x * 2)
     x4 = jnp.arange(4, dtype=jnp.float32)
     f.warm(x4)
     assert len(f.signatures) == 1
@@ -134,7 +134,7 @@ def test_aotfn_warm_then_call_no_stall():
 
 def test_aotfn_prefreeze_miss_is_not_a_post_freeze_stall():
     reg = AotRegistry()
-    f = reg.wrap("inc", jax.jit(lambda x: x + 1))
+    f = reg.wrap("inc", lambda x: x + 1)
     f(jnp.zeros((3,), jnp.float32))      # cold call before freeze
     assert len(reg.stalls) == 1 and not reg.stalls[0].frozen
     reg.freeze()
