@@ -103,9 +103,10 @@ def paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
     [num_pages, KVH, page_size, D]; block_tables: [B, max_pages] int32;
     seq_lens: [B] int32. Returns [B, H, D].
 
-    use_kernel=True routes to the fused Pallas kernel (block-table-driven
-    page DMA, interpret mode on the CPU); default is the jnp page-gather
-    oracle, which doubles as the portable fast path."""
+    use_kernel=True routes to the Pallas kernel, which copies only each
+    row's live pages (interpret mode on the CPU); the default is the jnp
+    page-gather oracle, the CPU path. Rows with ``seq_lens == 0`` differ
+    between the two and are finite in both."""
     if use_kernel:
         B, H, D = q.shape
         KVH = k_pages.shape[1]

@@ -86,9 +86,9 @@ def paged_attention_ref(q, k_pages, v_pages, block_tables, seq_lens):
 
     Gathers each row's pages into a contiguous [max_pages * page_size] view
     and runs masked softmax attention in f32 — this is both the allclose
-    target for the Pallas kernel and the portable jnp fast path the models
-    use off-TPU (the gather touches max_pages * page_size tokens, bounded by
-    per-request capacity instead of the engine-lifetime horizon).
+    target for the Pallas kernel and the jnp path the models use off-TPU
+    (the gather touches max_pages * page_size tokens of every row, live or
+    idle, where the kernel reads only each row's live pages).
     """
     B, H, D = q.shape
     KVH, Pg = k_pages.shape[1], k_pages.shape[2]

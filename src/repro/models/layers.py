@@ -264,10 +264,10 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens, *,
     Unlike ``decode_attention``'s shared-timeline cache, each row's work is
     bounded by its own capacity (max_pages * page_size) instead of the
     engine-lifetime horizon, and positions are 0-based per request — no
-    ``start`` masking, no RoPE offset bookkeeping. The jnp path gathers
-    pages (kernels/ref.py oracle); use_kernel routes to the fused Pallas
-    kernel (kernels/paged_attention.py) where the block table drives page
-    DMA directly.
+    ``start`` masking, no RoPE offset bookkeeping. use_kernel routes to
+    the Pallas kernel (kernels/paged_attention.py), which copies only each
+    row's live pages; the paged local backend binds it on a TPU. Otherwise
+    the jnp path gathers every table entry (the kernels/ref.py oracle).
     """
     from repro.kernels import ops as KO
     B, _, H, D = q.shape

@@ -389,10 +389,13 @@ class PagedLocalBackend:
         self._shape = (cfg.num_slots, num_pages, cfg.page_size,
                        pages_per_slot)
         self.reset_state()
-        # use_kernel is bound statically at jit time: fused Pallas paged
-        # attention on TPU, jnp page-gather otherwise
+        # bound statically at jit time: the length-bounded Pallas kernel on
+        # a TPU (or interpreted, with use_kernel), the jnp page gather
+        # otherwise
+        self.attn_path = "pallas" if cfg.use_kernel \
+            or jax.default_backend() == "tpu" else "gather"
         self._step = self.aot.wrap("decode_step", functools.partial(
-            api.decode_paged_fn, use_kernel=cfg.use_kernel))
+            api.decode_paged_fn, use_kernel=self.attn_path == "pallas"))
         seg_name = self.seg.name
 
         def insert(cache, kk, vv, pages, offs, slot, bt_row, seq_len):
@@ -572,6 +575,9 @@ class PagedPipelinedBackend:
         #                                         memoized jitted restage
         self._shape = (cfg.num_slots, num_pages, cfg.page_size,
                        pages_per_slot)
+        # the kernel needs every mesh axis manual, which only use_kernel
+        # arranges (runtime/pipeline.py)
+        self.attn_path = "pallas" if cfg.use_kernel else "gather"
         self._build(stage_blocks)
         self.reset_state()
 
@@ -2253,6 +2259,12 @@ class ServingEngine:
             assert self.global_len < self.config.max_seq - 1, \
                 "timeline horizon violated despite admission gating"
 
+        if self.kv_layout == "paged":
+            # pages the attention kernel streams (this step's token
+            # included) against the pages the block tables span
+            Pg = self.config.page_size
+            live_pages = sum(-(-(self.slot_len[slot] + 1) // Pg)
+                             for slot, _ in active)
         with self.telemetry.span("serve.dispatch"):
             tokens = jnp.asarray(self.pending)[:, None]
             t0 = time.perf_counter()
@@ -2289,6 +2301,10 @@ class ServingEngine:
         with self.telemetry.span("serve.telemetry"):
             if not self._in_warmup:
                 self.telemetry.record_step(wall)
+                if self.kv_layout == "paged":
+                    self.telemetry.record_decode_attention(
+                        live_pages,
+                        self.config.num_slots * self.pages_per_slot)
                 if self.steps % self.telemetry.interval == 0:
                     times = self.backend.stage_times()
                     if times is None:
@@ -2801,4 +2817,7 @@ class ServingEngine:
             out["prefill_chunk"] = self.config.prefill_chunk
             out["chunked_admissions"] = self.chunked_admissions
             out["prefill_chunks"] = self.chunk_steps
+            out["decode_attn_path"] = self.backend.attn_path
+            out["decode_attn_live_pages"] = self.telemetry.attn_live_pages
+            out["decode_attn_table_pages"] = self.telemetry.attn_table_pages
         return out

@@ -91,6 +91,10 @@ class StageTelemetry:
     _inject: Dict[int, float] = dataclasses.field(default_factory=dict)
     wall_s: float = 0.0
     steps_recorded: int = 0
+    # paged decode attention, summed over decode calls: pages of live rows
+    # (what the kernel streams) and pages the block tables span
+    attn_live_pages: int = 0
+    attn_table_pages: int = 0
     observations: int = 0
     span_s: Dict[str, float] = dataclasses.field(
         init=False, default_factory=lambda: dict.fromkeys(SPANS, 0.0))
@@ -114,6 +118,11 @@ class StageTelemetry:
     def record_step(self, wall_dt: float) -> None:
         self.wall_s += wall_dt
         self.steps_recorded += 1
+
+    def record_decode_attention(self, live_pages: int,
+                                table_pages: int) -> None:
+        self.attn_live_pages += live_pages
+        self.attn_table_pages += table_pages
 
     @contextlib.contextmanager
     def span(self, name: str, **meta) -> Iterator[None]:
@@ -143,6 +152,8 @@ class StageTelemetry:
         EMAs."""
         self.wall_s = 0.0
         self.steps_recorded = 0
+        self.attn_live_pages = 0
+        self.attn_table_pages = 0
         self.span_s = dict.fromkeys(SPANS, 0.0)
         self.span_n = dict.fromkeys(SPANS, 0)
         self._gc_base = (GC_PAUSES.seconds, GC_PAUSES.count)

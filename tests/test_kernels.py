@@ -6,6 +6,7 @@ import pytest
 
 from repro.kernels import ref as KR
 from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.paged_attention import pages_per_block
 from repro.kernels.seal import seal_pallas, unseal_pallas
 from repro.kernels import ops as KO
 
@@ -79,32 +80,73 @@ def test_flash_gqa_wrapper():
 
 
 # ---------------------------------------------------------------------------
-# Paged decode attention: fused kernel vs page-gather oracle
+# Paged decode attention: length-bounded kernel vs page-gather oracle
 # ---------------------------------------------------------------------------
-def _paged_case(seed, B, KVH, rep, D, Pg, MP):
+# A kernel row and an oracle row are both f32 values rounded to the pool's
+# dtype: f32 agrees to 3e-5, bf16 to one unit in the last place (2**-7).
+TOL = {np.float32: dict(atol=3e-5, rtol=3e-5),
+       jnp.bfloat16: dict(atol=1e-5, rtol=2.0 ** -7)}
+
+
+def _paged_case(seed, B, KVH, rep, D, Pg, MP, dtype=np.float32):
     rng = np.random.RandomState(seed)
     N = B * MP + 1                       # page 0 reserved null
-    q = jnp.asarray(rng.randn(B, KVH * rep, D).astype(np.float32))
-    kp = jnp.asarray(rng.randn(N, KVH, Pg, D).astype(np.float32))
-    vp = jnp.asarray(rng.randn(N, KVH, Pg, D).astype(np.float32))
+
+    def draw(*shape):
+        return jnp.asarray(rng.randn(*shape).astype(np.float32), dtype)
+
+    q = draw(B, KVH * rep, D)
+    kp = draw(N, KVH, Pg, D)
+    vp = draw(N, KVH, Pg, D)
     # distinct non-null pages per row, shuffled (layout independence)
     bt = rng.permutation(N - 1)[: B * MP].reshape(B, MP).astype(np.int32) + 1
     sl = rng.randint(1, MP * Pg + 1, size=B).astype(np.int32)
     return q, kp, vp, jnp.asarray(bt), jnp.asarray(sl)
 
 
-@pytest.mark.parametrize("B,KVH,rep,D,Pg,MP", [
-    (3, 2, 4, 16, 4, 5),
-    (2, 4, 1, 32, 8, 3),
-    (1, 1, 2, 64, 16, 2),
-    (4, 2, 2, 128, 8, 4),
+def _ragged_lens(B, KVH, D, Pg, MP, dtype):
+    """Idle, one token, a partial page, exactly one and two blocks of the
+    kernel's ``ppb`` pages, and a full table (the last row)."""
+    ppb = pages_per_block(Pg, KVH, D, jnp.dtype(dtype).itemsize, MP)
+    lens = [0, 1, Pg + 3, Pg * ppb, 2 * Pg * ppb][: B - 1] + [MP * Pg]
+    assert len(lens) == B and max(lens) == MP * Pg
+    return jnp.asarray(lens, jnp.int32)
+
+
+def _assert_rows_match(ker, ref, sl, dtype):
+    """Rows with tokens match the oracle; idle rows are finite."""
+    ker = np.asarray(ker, np.float32)
+    ref = np.asarray(ref, np.float32)
+    live = np.asarray(sl) > 0
+    np.testing.assert_allclose(ker[live], ref[live], **TOL[dtype])
+    assert np.isfinite(ker).all()
+
+
+@pytest.mark.parametrize("B,KVH,rep,D,Pg,MP,dtype,ragged", [
+    pytest.param(3, 2, 4, 16, 4, 5, np.float32, False, id="3-2-4-16-4-5"),
+    pytest.param(2, 4, 1, 32, 8, 3, np.float32, False, id="2-4-1-32-8-3"),
+    pytest.param(1, 1, 2, 64, 16, 2, np.float32, False, id="1-1-2-64-16-2"),
+    pytest.param(4, 2, 2, 128, 8, 4, np.float32, False, id="4-2-2-128-8-4"),
+    # GLM-4-9B (KVH 2, rep 16, D 128) and llama3.2-1b (KVH 8, rep 4, D 64)
+    # at page size 16 in bf16: several blocks a row, ragged lengths
+    pytest.param(6, 2, 16, 128, 16, 72, jnp.bfloat16, True,
+                 id="glm4-9b-bf16-ragged"),
+    pytest.param(6, 8, 4, 64, 16, 36, jnp.bfloat16, True,
+                 id="llama3.2-1b-bf16-ragged"),
+    pytest.param(4, 2, 16, 128, 16, 40, jnp.bfloat16, False,
+                 id="glm4-9b-bf16"),
+    pytest.param(4, 8, 4, 64, 16, 20, jnp.bfloat16, False,
+                 id="llama3.2-1b-bf16"),
+    pytest.param(5, 2, 2, 32, 8, 9, np.float32, True, id="lane-packed-ragged"),
 ])
-def test_paged_kernel_matches_oracle(B, KVH, rep, D, Pg, MP):
-    q, kp, vp, bt, sl = _paged_case(7, B, KVH, rep, D, Pg, MP)
+def test_paged_kernel_matches_oracle(B, KVH, rep, D, Pg, MP, dtype, ragged):
+    q, kp, vp, bt, sl = _paged_case(7, B, KVH, rep, D, Pg, MP, dtype)
+    if ragged:
+        sl = _ragged_lens(B, KVH, D, Pg, MP, dtype)
     ker = KO.paged_attention(q, kp, vp, bt, sl, use_kernel=True)
     ref = KO.paged_attention(q, kp, vp, bt, sl, use_kernel=False)
-    np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
-                               atol=3e-5, rtol=3e-5)
+    assert ker.dtype == ref.dtype == q.dtype
+    _assert_rows_match(ker, ref, sl, dtype)
 
 
 def test_paged_kernel_idle_rows_are_finite():
@@ -117,12 +159,19 @@ def test_paged_kernel_idle_rows_are_finite():
         assert np.isfinite(np.asarray(out)).all()
 
 
-@pytest.mark.parametrize("B,KVH,rep,D,Pg,MP,shared", [
-    (3, 2, 4, 16, 4, 5, 2),
-    (4, 2, 2, 32, 8, 4, 3),
-    (2, 1, 2, 64, 16, 3, 1),
+@pytest.mark.parametrize("B,KVH,rep,D,Pg,MP,shared,dtype", [
+    pytest.param(3, 2, 4, 16, 4, 5, 2, np.float32, id="3-2-4-16-4-5-2"),
+    pytest.param(4, 2, 2, 32, 8, 4, 3, np.float32, id="4-2-2-32-8-4-3"),
+    pytest.param(2, 1, 2, 64, 16, 3, 1, np.float32, id="2-1-2-64-16-3-1"),
+    # the shared prefix ends inside a row's second block of ppb = 32 pages
+    # (GLM) or 16 (llama), so that block reads shared, then private pages
+    pytest.param(3, 2, 16, 128, 16, 48, 37, jnp.bfloat16,
+                 id="glm4-9b-bf16-shared-37"),
+    pytest.param(3, 8, 4, 64, 16, 24, 19, jnp.bfloat16,
+                 id="llama3.2-1b-bf16-shared-19"),
 ])
-def test_paged_kernel_shared_tables_parity(B, KVH, rep, D, Pg, MP, shared):
+def test_paged_kernel_shared_tables_parity(B, KVH, rep, D, Pg, MP, shared,
+                                           dtype):
     """COW prefix sharing aliases block-table entries: several rows point at
     the SAME physical prefix pages (demand paging, DESIGN.md §Demand
     paging). The gather path is indifferent to aliasing by construction;
@@ -130,9 +179,13 @@ def test_paged_kernel_shared_tables_parity(B, KVH, rep, D, Pg, MP, shared):
     rng = np.random.RandomState(11)
     # `shared` common prefix pages + per-row private tails
     N = shared + B * (MP - shared) + 1
-    q = jnp.asarray(rng.randn(B, KVH * rep, D).astype(np.float32))
-    kp = jnp.asarray(rng.randn(N, KVH, Pg, D).astype(np.float32))
-    vp = jnp.asarray(rng.randn(N, KVH, Pg, D).astype(np.float32))
+
+    def draw(*shape):
+        return jnp.asarray(rng.randn(*shape).astype(np.float32), dtype)
+
+    q = draw(B, KVH * rep, D)
+    kp = draw(N, KVH, Pg, D)
+    vp = draw(N, KVH, Pg, D)
     bt = np.zeros((B, MP), np.int32)
     nxt = shared + 1
     for b in range(B):
@@ -145,8 +198,7 @@ def test_paged_kernel_shared_tables_parity(B, KVH, rep, D, Pg, MP, shared):
     bt, sl = jnp.asarray(bt), jnp.asarray(sl)
     ker = KO.paged_attention(q, kp, vp, bt, sl, use_kernel=True)
     ref = KO.paged_attention(q, kp, vp, bt, sl, use_kernel=False)
-    np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
-                               atol=3e-5, rtol=3e-5)
+    _assert_rows_match(ker, ref, sl, dtype)
     # aliasing really is invisible: materializing each row's pages into a
     # private copy of the pool changes nothing
     for b in range(B):
@@ -155,7 +207,8 @@ def test_paged_kernel_shared_tables_parity(B, KVH, rep, D, Pg, MP, shared):
         priv_vp = jnp.concatenate([vp[:1], vp[bt[b]]], axis=0)
         one = KO.paged_attention(q[b:b + 1], priv_kp, priv_vp, priv_bt,
                                  sl[b:b + 1], use_kernel=False)
-        np.testing.assert_allclose(np.asarray(one)[0], np.asarray(ref)[b],
+        np.testing.assert_allclose(np.asarray(one, np.float32)[0],
+                                   np.asarray(ref, np.float32)[b],
                                    atol=2e-5, rtol=2e-5)
 
 

@@ -408,3 +408,58 @@ def test_paged_pool_restage_roundtrip_token_exact(setup):
     r2 = _drive(e2, wl)
     assert all(r.status == DONE for r in r1 + r2)
     assert [r.generated for r in r1] == [r.generated for r in r2]
+
+
+# ---------------------------------------------------------------------------
+# Decode attention: which path the local backend binds, what it streams
+# ---------------------------------------------------------------------------
+def test_local_backend_binds_gather_off_tpu_and_pallas_on_request(setup):
+    """Off a TPU the paged local backend decodes through the jnp page
+    gather; ``use_kernel`` binds the (here interpreted) Pallas kernel."""
+    cfg, api, params = setup
+    assert jax.default_backend() == "cpu"
+    plain = _engine(api, params, request_capacity=24)
+    kernel = _engine(api, params, request_capacity=24, use_kernel=True)
+    assert plain.stats()["decode_attn_path"] == "gather"
+    assert kernel.stats()["decode_attn_path"] == "pallas"
+
+
+def test_kernel_decode_token_exact_with_idle_slots_and_shared_prefix(setup):
+    """The interpreted kernel serves the same streams as the page gather
+    over several decode steps, with a slot always idle (3 requests, 4
+    slots) and block tables that alias a COW-shared prefix."""
+    cfg, api, params = setup
+    rng = np.random.RandomState(5)
+    sys_prompt = rng.randint(0, cfg.vocab_size, size=10).tolist()
+    wl = [(sys_prompt + rng.randint(0, cfg.vocab_size,
+                                    size=int(n)).tolist(), 6, None, 1)
+          for n in (2, 5, 3)]
+    oracle = _drive_checked(_engine(api, params, request_capacity=24,
+                                    page_policy="demand"), wl)
+    eng = _engine(api, params, request_capacity=24, page_policy="demand",
+                  use_kernel=True)
+    got = _drive_checked(eng, wl)
+    assert got == oracle
+    st = eng.stats()
+    assert st["decode_attn_path"] == "pallas" and st["cow_hits"] > 0
+    assert st["peak_running_slots"] < eng.config.num_slots
+    _assert_null_page_zero(eng, api)
+
+
+def test_decode_attention_page_counters(setup):
+    """Prompts of 3, 8 and 5 tokens, three tokens each: the prefill gives
+    the first, two decode calls the rest. At page size 4 the calls attend
+    over 4, 9, 6 and then 5, 10, 7 tokens: 1 + 3 + 2 and 2 + 3 + 2 live
+    pages; the tables span 4 slots x 6 pages each call."""
+    cfg, api, params = setup
+    eng = _engine(api, params, request_capacity=24)
+    assert eng.pages_per_slot == 6
+    rng = np.random.RandomState(6)
+    for n in (3, 8, 5):
+        eng.submit(rng.randint(0, cfg.vocab_size, size=n).tolist(), 3)
+    while eng.scheduler.has_work():
+        eng.step()
+    st = eng.stats()
+    assert eng.telemetry.steps_recorded == 2
+    assert st["decode_attn_live_pages"] == 6 + 7
+    assert st["decode_attn_table_pages"] == 2 * 4 * 6

@@ -1,5 +1,6 @@
 """Compile-only checks of the serving path's Pallas kernels for a TPU v5e,
-at llama3.2-1b widths, against a described (not attached) v5e:2x2 chip.
+at llama3.2-1b widths and at the GLM-4-9B benchmark cells' paged-attention
+shapes, against a described (not attached) v5e:2x2 chip.
 
 Nothing runs: each test lowers a kernel with ``interpret=False`` and
 compiles it with the TPU compiler, which refuses what the chip would (a
@@ -24,6 +25,10 @@ from repro.kernels.seal import (_block_shape, seal_bits_pallas, seal_pallas,
                                 unseal_bits_pallas, unseal_pallas)
 
 CFG = get_arch("llama3.2-1b")
+GLM = get_arch("glm4-9b")
+# the GLM-4-9B cells (benchmarks/onchip): 64 slots, request capacity 5120
+# (320 pages of 16 tokens a slot), a 1.95 GiB bf16 pool of 6390 pages
+CELL_SLOTS, CELL_PAGES_PER_SLOT, CELL_NUM_PAGES = 64, 5120 // 16, 6390
 PAGE = 16                               # tokens per KV page
 PAGES_PER_SLOT = 2048 // PAGE           # request_capacity 2048
 SLOTS = 16
@@ -56,18 +61,32 @@ def _compiled_text(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def test_paged_attention_compiles_for_v5e(one_chip):
-    rep = CFG.num_heads // CFG.num_kv_heads
-    num_pages = SLOTS * PAGES_PER_SLOT + 1
-    q = _sds((SLOTS, CFG.num_kv_heads, rep, CFG.head_dim), jnp.bfloat16,
+def _paged_attention_text(one_chip, cfg, slots, pages_per_slot, num_pages):
+    rep = cfg.num_heads // cfg.num_kv_heads
+    q = _sds((slots, cfg.num_kv_heads, rep, cfg.head_dim), jnp.bfloat16,
              one_chip)
-    pool = _sds((num_pages, CFG.num_kv_heads, PAGE, CFG.head_dim),
+    pool = _sds((num_pages, cfg.num_kv_heads, PAGE, cfg.head_dim),
                 jnp.bfloat16, one_chip)
-    bt = _sds((SLOTS, PAGES_PER_SLOT), jnp.int32, one_chip)
-    sl = _sds((SLOTS,), jnp.int32, one_chip)
-    text = _compiled_text(
+    bt = _sds((slots, pages_per_slot), jnp.int32, one_chip)
+    sl = _sds((slots,), jnp.int32, one_chip)
+    return _compiled_text(
         lambda *a: paged_attention_pallas(*a, interpret=False),
         q, pool, pool, bt, sl)
+
+
+def test_paged_attention_compiles_for_v5e(one_chip):
+    """The decode kernel at the GLM-4-9B cells' shapes: KVH 2, rep 16,
+    head_dim 128, 64 slots of 320 pages."""
+    text = _paged_attention_text(one_chip, GLM, CELL_SLOTS,
+                                 CELL_PAGES_PER_SLOT, CELL_NUM_PAGES)
+    assert "tpu_custom_call" in text
+
+
+def test_lane_packed_paged_attention_compiles_for_v5e(one_chip):
+    """llama3.2-1b's 64-wide heads, read two positions to a 128-lane row
+    (chip_smoke.py serves it through the same kernel)."""
+    text = _paged_attention_text(one_chip, CFG, SLOTS, PAGES_PER_SLOT,
+                                 SLOTS * PAGES_PER_SLOT + 1)
     assert "tpu_custom_call" in text
 
 
