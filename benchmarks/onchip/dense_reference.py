@@ -25,7 +25,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -157,10 +157,20 @@ def teacher_forced(weights, m: Dict, capacity: int, prompt: Sequence[int],
 
 
 def served_gaps(weights, m: Dict, capacity: int,
-                sample: List[Tuple[Sequence[int], Sequence[int]]]
-                ) -> List[np.ndarray]:
+                sample: List[Tuple[Sequence[int], Sequence[int]]],
+                plan: Optional[Dict] = None) -> List[np.ndarray]:
     """The f32 reference's gap for every served token of every
-    ``(prompt, served)`` pair."""
+    ``(prompt, served)`` pair. ``plan`` is how the engine served them
+    (``stage_blocks``, ``seal_boundary``): this reference has no cuts, so
+    it takes an unsealed plan and refuses a sealed one, whose int8 seal
+    changes the logits at every cut by design."""
+    if plan is not None and plan.get("seal_boundary"):
+        raise ValueError(
+            f"the engine sealed the activations at the cuts of stages "
+            f"{tuple(plan['stage_blocks'])}; dense_reference has no cuts. A "
+            f"configuration served sealed names a reference module that "
+            f"rounds at the same cuts (one scale per row, max(absmax, "
+            f"1e-6) / 127, then clip(round(x / scale), -127, 127) * scale)")
     return [teacher_forced(weights, m, capacity, p, s)[0] for p, s in sample]
 
 

@@ -2,6 +2,12 @@
 jitted call, in the layout the served program takes and in the type it
 serves (bfloat16).
 
+Over a ``("pod", ...)`` mesh every leaf with a layer axis is drawn sharded
+by layer over ``pod`` and the rest replicated, so no chip ever holds the
+whole model: the layout a pipeline of one stage per pod takes as it is.
+The values for a seed do not depend on the mesh (``jax_threefry_partitionable``
+draws a sharded array as it draws a whole one).
+
 Scales keep every logit of order one: projections are N(0, 1/fan_in), the
 norms start at one, and the embedding and the head are N(0, 1/d_model), so
 the tied head of Command-R and the separate head of GLM-4 give logits of
@@ -14,6 +20,7 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 
 def base_key(seed: int):
@@ -48,8 +55,10 @@ def _is_leaf(x) -> bool:
     return isinstance(x, tuple) and len(x) == 2 and isinstance(x[0], tuple)
 
 
-def init(m: Dict[str, Any], seed: int, dtype=jnp.bfloat16):
-    """All weights of model ``m`` from ``seed``, on the default device."""
+def init(m: Dict[str, Any], seed: int, dtype=jnp.bfloat16, mesh=None):
+    """All weights of model ``m`` from ``seed``: on the default device, or
+    with ``mesh`` the blocks sharded by layer over its ``pod`` axis and the
+    embedding, final norm and head replicated."""
     spec = shapes(m)
     leaves, treedef = jax.tree.flatten(spec, is_leaf=_is_leaf)
 
@@ -65,4 +74,10 @@ def init(m: Dict[str, Any], seed: int, dtype=jnp.bfloat16):
                             * jnp.asarray(std, dtype)).astype(dtype))
         return jax.tree.unflatten(treedef, out)
 
-    return jax.jit(draw)(base_key(seed))
+    if mesh is None:
+        return jax.jit(draw)(base_key(seed))
+    by_layer = NamedSharding(mesh, P("pod"))
+    whole = NamedSharding(mesh, P())
+    out = {k: jax.tree.map(lambda _: by_layer if k == "blocks" else whole,
+                           v, is_leaf=_is_leaf) for k, v in spec.items()}
+    return jax.jit(draw, out_shardings=out)(base_key(seed))

@@ -7,13 +7,19 @@ or a file of its own, found by the name in ``BENCHMARK.json``:
 
 * ``configs/<config>.json``: sizes (in the source's own key names), the map
   from them to the program's architecture fields, the modules that draw
-  its weights and hold its plain reference (``<module>.py`` here), the KV
-  pool's bytes and page size;
+  its weights, hold its plain reference and count its work (``costs``, by
+  default ``costs``; each ``<module>.py`` here), the KV pool's bytes over
+  all layers and page size, and optionally how the cell is served
+  (``serve``: ``backend``, ``local`` or ``pipelined``, and fields of the
+  engine's ``EngineConfig``; see ``serving_plan``);
 * ``traffic/<mix>.json``: the mix (see ``traffic.py``; its arrival process
   is a module ``arrivals/<process>.py``), the engine's shapes and the
   correctness limits of the cell;
 * ``metrics/<metric>.py``: a reader ``read(run) -> float | None`` of a
-  ``Run`` record; ``None`` means there was nothing to read.
+  ``Run`` record; ``None`` means there was nothing to read. A run records
+  every span and numeric stat of the engine (``counters``) and, traced,
+  each program's device time and each named scope's self time
+  (``reduce_trace``).
 """
 from __future__ import annotations
 
@@ -41,7 +47,6 @@ if SRC not in sys.path:
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-import costs  # noqa: E402
 import traffic as traffic_gen  # noqa: E402
 from peaks import peaks_for  # noqa: E402
 
@@ -70,6 +75,8 @@ class Cell:
     mix: Dict             # traffic/<mix>.json
     end_to_end: List[Dict]
     per_layer: List[Dict]
+    backend: str          # "local" or "pipelined"
+    engine: Dict          # EngineConfig fields but the pool's pages
 
 
 def _applies(metric: Dict, cell: str) -> bool:
@@ -86,9 +93,45 @@ def find_cell(bench: Dict, name: str, root: str = REPO,
     cfg_entry = {c["name"]: c for c in bench["configs"]}[w["config"]]
     config = load_json(os.path.join(root, cfg_entry["file"]))
     mix = load_json(os.path.join(traffic_dir, w["traffic"] + ".json"))
+    backend, engine = serving_plan(name, w["chips"], config, mix)
     return Cell(name, w["chips"], config, mix,
                 [m for m in bench["end_to_end"] if _applies(m, name)],
-                [m for m in bench["per_layer"] if _applies(m, name)])
+                [m for m in bench["per_layer"] if _applies(m, name)],
+                backend, engine)
+
+
+# the engine every cell gets; a configuration's "serve" changes fields of it
+ENGINE = dict(num_stages=1, num_microbatches=1, page_policy="demand",
+              preempt_policy="swap", prefix_sharing=True)
+# fields the mix and the configuration's pool set, which "serve" may not
+SIZED_ELSEWHERE = ("num_slots", "prompt_capacity", "request_capacity",
+                   "prefill_chunk", "page_size", "num_pages")
+
+
+def serving_plan(name: str, chips: int, config: Dict, mix: Dict):
+    """The backend and the ``EngineConfig`` fields (the pool's pages left
+    to ``build_engine``) that serve cell ``name``. Without ``serve`` in the
+    configuration: the local backend on one stage. A plan whose stages do
+    not match the cell's chips is refused here, before anything is built."""
+    from repro.serving import EngineConfig
+    serve = dict(config.get("serve", {}))
+    backend = serve.pop("backend", "local")
+    known = {f.name for f in dataclasses.fields(EngineConfig)}
+    bad = sorted(k for k in serve if k not in known or k in SIZED_ELSEWHERE)
+    if backend not in ("local", "pipelined") or bad:
+        raise ValueError(f"cell {name!r}: serve has backend {backend!r} "
+                         f"and fields it may not set: {bad}")
+    eng_mix = mix["engine"]
+    fields = dict(ENGINE, **serve,
+                  **{k: eng_mix[k] for k in ("num_slots", "prompt_capacity",
+                                              "request_capacity",
+                                              "prefill_chunk")},
+                  page_size=config["page_size"])
+    if fields["num_stages"] != chips:
+        raise ValueError(f"cell {name!r}: serve.num_stages "
+                         f"{fields['num_stages']} differs from the cell's "
+                         f"{chips} chips")
+    return backend, fields
 
 
 def model_dims(config: Dict) -> Dict[str, Any]:
@@ -106,8 +149,14 @@ def reader(name: str) -> Callable:
 
 
 def weights_of(cell: Cell):
-    """The module that draws the configuration's weights."""
+    """The module that draws the configuration's weights:
+    ``init(m, seed, mesh=None)``."""
     return importlib.import_module(cell.config["weights"])
+
+
+def costs_of(cell: Cell):
+    """The module that counts the configuration's needed work."""
+    return importlib.import_module(cell.config.get("costs", "costs"))
 
 
 def reference_of(cell: Cell):
@@ -138,7 +187,18 @@ def use_compile_cache(path: str = CACHE_DIR) -> None:
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 
-def build_engine(cell: Cell, params, m: Dict):
+def serving_mesh(cell: Cell, devices):
+    """The ``(chips, 1)`` ``("pod", "data")`` mesh over ``devices`` that a
+    pipelined cell is served on; None for the local backend."""
+    if cell.backend != "pipelined":
+        return None
+    from jax.sharding import AxisType
+    return jax.make_mesh((cell.chips, 1), ("pod", "data"),
+                         axis_types=(AxisType.Auto,) * 2,
+                         devices=list(devices))
+
+
+def build_engine(cell: Cell, params, m: Dict, mesh=None):
     from repro.configs import get_arch
     from repro.models.api import build_model
     from repro.serving import EngineConfig, ServingEngine
@@ -151,17 +211,12 @@ def build_engine(cell: Cell, params, m: Dict):
                         api.abstract_params())
     got = jax.tree.map(lambda a: (a.shape, str(a.dtype)), params)
     assert want == got, "drawn weights do not match the program's layout"
-    Pg = cell.config["page_size"]
-    page_bytes = Pg * costs.kv_bytes_per_token(m)
+    page_bytes = cell.config["page_size"] \
+        * costs_of(cell).kv_bytes_per_token(m)
     num_pages = cell.config["kv_pool_bytes"] // page_bytes + 1  # + null page
-    ec = EngineConfig(
-        num_slots=eng_mix["num_slots"], num_stages=1, num_microbatches=1,
-        prompt_capacity=eng_mix["prompt_capacity"],
-        request_capacity=eng_mix["request_capacity"], page_size=Pg,
-        num_pages=int(num_pages), page_policy="demand",
-        preempt_policy="swap", prefix_sharing=True,
-        prefill_chunk=eng_mix["prefill_chunk"])
-    return ServingEngine(api, config=ec, params=params, backend="local")
+    ec = EngineConfig(num_pages=int(num_pages), **cell.engine)
+    return ServingEngine(api, mesh=mesh, config=ec, params=params,
+                         backend=cell.backend)
 
 
 # ---------------------------------------------------------------------------
@@ -190,8 +245,10 @@ class Run:
     counters: Dict[str, float]    # deltas over the window
     flops: float                  # needed work inside the window
     bytes: float
-    peaks: Dict[str, float]
-    trace: Optional[Dict] = None  # trace_reduce.reduce() of the traced part
+    peaks: Dict[str, float]       # of one chip
+    chips: int = 1
+    trace: Optional[Dict] = None  # reduce_trace() of the traced part
+    traced_attn_bytes: float = 0.0  # paged attention's, traced steps
 
     def in_window(self, t: float) -> bool:
         return 0.0 <= t <= self.seconds
@@ -210,12 +267,15 @@ class Window:
 
     def __init__(self, eng, cell: Cell, m: Dict, clock=time.perf_counter):
         self.eng, self.cell, self.m, self.clock = eng, cell, m, clock
+        self.costs = costs_of(cell)
         self.Pg = cell.config["page_size"]
         self.live: Dict[int, Track] = {}      # rid -> track, not finished
         self.tracks: List[Track] = []
         self.flops = 0.0
         self.bytes = 0.0
         self.t0 = 0.0
+        self.tracing = False                  # the profiler traces
+        self.traced_attn_bytes = 0.0
         self.late: List[float] = []           # generator lateness, seconds
         self._chunk_pos: Dict[int, int] = {}
 
@@ -263,8 +323,11 @@ class Window:
             if tr.req.status == "done":
                 del self.live[rid]
         if account and contexts:
-            self.flops += costs.decode_flops(self.m, contexts)
-            self.bytes += costs.decode_bytes(self.m, contexts)
+            self.flops += self.costs.decode_flops(self.m, contexts)
+            self.bytes += self.costs.decode_bytes(self.m, contexts)
+            if self.tracing:
+                self.traced_attn_bytes += self.costs.paged_attention_bytes(
+                    self.m, contexts)
 
     def _account_admit(self, tr: Track, d: Dict) -> None:
         """The prefill an admission needed: the prompt's tokens past the
@@ -273,9 +336,9 @@ class Window:
             return
         P = tr.prompt_len + d.get("resumed_at", 0)
         sh = min(d.get("shared", 0) * self.Pg, P)
-        self.flops += costs.prefill_flops(self.m, sh, P - sh)
+        self.flops += self.costs.prefill_flops(self.m, sh, P - sh)
         if "chunks" not in d:       # a chunked prompt's bytes go per chunk
-            self.bytes += costs.prefill_bytes(self.m, sh, P - sh)
+            self.bytes += self.costs.prefill_bytes(self.m, sh, P - sh)
 
     def _account_chunk(self, d: Dict) -> None:
         rid, end = d["rid"], d["pos"]
@@ -283,7 +346,7 @@ class Window:
         if end <= start:
             start = 0
         self._chunk_pos[rid] = end
-        self.bytes += costs.prefill_bytes(self.m, start, end - start)
+        self.bytes += self.costs.prefill_bytes(self.m, start, end - start)
 
 
 def _sleep_until(clock, t: float) -> None:
@@ -295,14 +358,29 @@ def _sleep_until(clock, t: float) -> None:
 
 
 def counters(eng) -> Dict[str, float]:
+    """The engine's running totals: a few named ones, the seconds and
+    count of every program span (``span_s.<name>``, ``span_n.<name>``) and
+    every numeric top-level entry of ``stats()`` (``stat.<key>``)."""
     st = eng.stats()
-    return {"preemptions": st.get("preemptions", 0),
-            "swap_outs": st.get("swap_outs", 0),
-            "swap_ins": st.get("swap_ins", 0),
-            "compiles": eng.aot.post_freeze_compiles or 0,
-            "decode_wall_s": eng.telemetry.wall_s,
-            "decode_steps": eng.telemetry.steps_recorded,
-            "failed": len(eng.failed)}
+    out = {"preemptions": st.get("preemptions", 0),
+           "swap_outs": st.get("swap_outs", 0),
+           "swap_ins": st.get("swap_ins", 0),
+           "compiles": eng.aot.post_freeze_compiles or 0,
+           "decode_wall_s": eng.telemetry.wall_s,
+           "decode_steps": eng.telemetry.steps_recorded,
+           "failed": len(eng.failed)}
+    for name, (sec, n) in eng.telemetry.span_totals().items():
+        out[f"span_s.{name}"], out[f"span_n.{name}"] = sec, n
+    out.update((f"stat.{k}", v) for k, v in st.items()
+               if isinstance(v, (int, float)) and not isinstance(v, bool))
+    return out
+
+
+def window_delta(c0: Dict[str, float], c1: Dict[str, float]
+                 ) -> Dict[str, float]:
+    """Each counter's growth over the window; one first seen inside it
+    started at 0."""
+    return {k: v - c0.get(k, 0) for k, v in c1.items()}
 
 
 def serve_window(win: Window, tr_gen, seconds: float, lead_in: float,
@@ -333,6 +411,7 @@ def serve_window(win: Window, tr_gen, seconds: float, lead_in: float,
             jax.profiler.start_trace(trace_dir)
             traced = jax.profiler.TraceAnnotation("bench.window")
             traced.__enter__()
+            win.tracing = True
         with span("submit"):
             while k < len(pending) and t_traffic + pending[k].due_s <= now:
                 r = pending[k]
@@ -350,6 +429,7 @@ def serve_window(win: Window, tr_gen, seconds: float, lead_in: float,
             with span("wait"):
                 _sleep_until(clock, min(nxt_due, t_end, now + 0.05))
     if traced is not None:
+        win.tracing = False
         traced.__exit__(None, None, None)
         jax.profiler.stop_trace()
     c1 = counters(eng)
@@ -358,8 +438,7 @@ def serve_window(win: Window, tr_gen, seconds: float, lead_in: float,
         log(f"generator lateness: p50 {np.percentile(late, 50):.3f} ms, "
             f"p99 {np.percentile(late, 99):.3f} ms, max {late.max():.3f} ms "
             f"over {len(late)} submissions")
-    c0 = c0 or c1
-    return {k: c1[k] - c0[k] for k in c0}
+    return window_delta(c0 or c1, c1)
 
 
 def steadiness(run: "Run", tr_gen) -> Dict[str, float]:
@@ -407,13 +486,16 @@ def pick_sample(tracks: List[Track], seed: int, tokens: int,
     return pick
 
 
-def check(params, m: Dict, cell: Cell, sample: List[Track]
-          ) -> Dict[str, Dict]:
-    """The numbers compared, each with its limit."""
+def check(params, m: Dict, cell: Cell, sample: List[Track],
+          plan: Optional[Dict] = None) -> Dict[str, Dict]:
+    """The numbers compared, each with its limit. ``plan`` (how the engine
+    served, for a configuration with ``serve``) goes to the reference."""
     lim = cell.mix["check"]
     pairs = [(list(t.req.prompt), list(t.req.generated)) for t in sample]
     cap = cell.mix["engine"]["request_capacity"]
-    gaps = reference_of(cell).served_gaps(params, m, cap, pairs)
+    ref = reference_of(cell)
+    gaps = ref.served_gaps(params, m, cap, pairs) if plan is None \
+        else ref.served_gaps(params, m, cap, pairs, plan=plan)
     served = int(sum(len(g) for g in gaps))
     widest = float(max((g.max() for g in gaps if len(g)), default=math.inf))
     return {
@@ -454,10 +536,11 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_process: float,
     ``keep`` receives the sample and the weights."""
     log = log or (lambda s: print(s, file=sys.stderr, flush=True))
     m = model_dims(cell.config)
-    params = weights_of(cell).init(m, seed)
+    mesh = serving_mesh(cell, devices)
+    params = weights_of(cell).init(m, seed, mesh=mesh)
     jax.block_until_ready(params)
     log(f"weights drawn: {time.time() - t_process:.1f} s since start")
-    eng = build_engine(cell, params, m)
+    eng = build_engine(cell, params, m, mesh)
     t0 = time.perf_counter()
     eng.warmup()
     log(f"engine: {eng.pool.num_pages} pages x {eng.config.page_size}, "
@@ -480,15 +563,24 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_process: float,
     tracks = win.tracks
     attempted = len(tracks)
     rec = Run(cell.name, seconds, setup_s, tracks, delta, win.flops,
-              win.bytes, peaks)
+              win.bytes, peaks, chips=len(devices),
+              traced_attn_bytes=win.traced_attn_bytes)
     log(f"steady state: {steadiness(rec, tr_gen)}")
     sample = pick_sample(tracks, seed, cell.mix["check"]["sample_tokens"],
                          cell.mix["engine"]["prefill_chunk"])
+    plan = None
+    if "serve" in cell.config:
+        plan = {"stage_blocks": tuple(eng.stage_blocks),
+                "seal_boundary": eng.backend_kind == "pipelined"
+                and eng.config.seal_boundary}
+    if trace_dir is not None:
+        import trace_spans
+        scopes = trace_spans.program_scopes(eng)
     # the engine's state goes before the reference runs beside the weights
     del win, eng
     gc.collect()
     t_ref = time.perf_counter()
-    checks = check(params, m, cell, sample)
+    checks = check(params, m, cell, sample, plan)
     checks["failed_requests"] = {"value": int(delta["failed"]), "limit": 0}
     log(f"reference over {len(sample)} requests: "
         f"{time.perf_counter() - t_ref:.1f} s")
@@ -498,13 +590,30 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_process: float,
         import trace_reduce
         t_tr = time.perf_counter()
         try:
-            rec.trace = trace_reduce.reduce_file(
-                trace_reduce.find_xplane(trace_dir))
+            rec.trace = reduce_trace(trace_reduce.find_xplane(trace_dir),
+                                     scopes)
         except (FileNotFoundError, ValueError) as e:
             log(f"trace not reduced: {e}")
         shutil.rmtree(trace_dir, ignore_errors=True)
         log(f"trace read in {time.perf_counter() - t_tr:.1f} s")
     return Outcome(rec, checks, attempted, int(delta["failed"]), peak)
+
+
+def reduce_trace(path: str, scopes: Dict[str, Dict[str, str]]) -> Dict:
+    """``trace_reduce``'s busy time, window, top operations and idle gaps,
+    which the result line and ``device_idle_share`` read, with each
+    program's device seconds and runs (``programs``: ``[name, seconds,
+    runs]``) and each named scope's device self seconds per program
+    (``scopes``: ``[<program>/<scope>, seconds]``) from ``trace_spans``;
+    seconds are means over the device planes (``devices``)."""
+    import trace_reduce
+    import trace_spans
+    devices, host, programs = trace_spans.read(path, scopes)
+    out = trace_reduce.reduce({d: [op[:3] for op in ops]
+                               for d, ops in devices.items()}, host)
+    spans = trace_spans.reduce(devices, host, programs=programs)
+    out["programs"], out["scopes"] = spans["programs"], spans["scopes"]
+    return out
 
 
 def result(cell: Cell, out: Outcome, trace: bool, devices) -> Dict:

@@ -54,12 +54,13 @@ def main(argv=None) -> int:
     harness.use_compile_cache()
     log = lambda s: print(s, file=sys.stderr, flush=True)  # noqa: E731
     m = harness.model_dims(cell.config)
-    params = harness.weights_of(cell).init(m, args.seed)
+    mesh = harness.serving_mesh(cell, devices)
+    params = harness.weights_of(cell).init(m, args.seed, mesh=mesh)
     peaks = harness.peaks_for(devices[0].device_kind)
     lead_in = cell.mix.get("lead_in_s", 0.0)
     knee = None
     for i, rate in enumerate(float(r) for r in args.rates.split(",")):
-        eng = harness.build_engine(cell, params, m)
+        eng = harness.build_engine(cell, params, m, mesh)
         eng.warmup()
         mix = dict(cell.mix, arrivals=dict(cell.mix["arrivals"],
                                            rate_per_s=rate))
@@ -69,7 +70,7 @@ def main(argv=None) -> int:
         delta = harness.serve_window(win, tr_gen, args.seconds, lead_in,
                                      None, 0, log)
         run = harness.Run(cell.name, args.seconds, 0.0, win.tracks, delta,
-                          win.flops, win.bytes, peaks)
+                          win.flops, win.bytes, peaks, chips=len(devices))
         row = {"rate_per_s": rate, "warm": len(tr_gen.warm),
                "due": sum(t.in_window for t in win.tracks)}
         row.update(harness.steadiness(run, tr_gen))

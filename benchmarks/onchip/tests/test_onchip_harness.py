@@ -191,3 +191,45 @@ def test_benchmark_json_is_whole():
     for m in metrics:
         for w in m.get("workloads", []):
             assert w in cells
+
+
+def test_reference_takes_an_unsealed_plan_and_refuses_a_sealed_one(chat):
+    """Without a plan, or with an unsealed one, the reference gives the
+    gaps the run compared; a sealed plan is refused by name."""
+    cell, out, _, keep = chat
+    pairs = [(list(t.req.prompt), list(t.req.generated))
+             for t in keep["sample"]]
+    cap = cell.mix["engine"]["request_capacity"]
+    ref = harness.reference_of(cell)
+    plain = ref.served_gaps(keep["params"], keep["m"], cap, pairs)
+    assert max(g.max() for g in plain) == \
+        out.checks["max_logit_gap"]["value"]
+    for plan in (None, {"stage_blocks": (2,), "seal_boundary": False}):
+        got = ref.served_gaps(keep["params"], keep["m"], cap, pairs,
+                              plan=plan)
+        for a, b in zip(got, plain):
+            np.testing.assert_array_equal(a, b)
+    with pytest.raises(ValueError, match="rounds at the same cuts"):
+        ref.served_gaps(keep["params"], keep["m"], cap, pairs,
+                        plan={"stage_blocks": (1, 1), "seal_boundary": True})
+
+
+def test_spans_and_stats_reach_the_counters(chat):
+    """Every program span and numeric stat of the engine reaches the run's
+    counters as a window delta, one first seen inside the window
+    included."""
+    cell, out, _, keep = chat
+    c = out.run.counters
+    assert c["span_s.serve.step"] > c["span_s.serve.device_wait"] > 0
+    assert c["span_n.serve.dispatch"] == c["decode_steps"] > 0
+    assert c["stat.tokens_out"] > 0 and "stat.backend" not in c
+    assert harness.reader("host_ms_per_step")(out.run) > 0
+    eng = harness.build_engine(cell, keep["params"], keep["m"])
+    c0 = harness.counters(eng)
+    with eng.telemetry.span("bench.test_only"):
+        pass
+    delta = harness.window_delta(c0, harness.counters(eng))
+    assert "span_n.bench.test_only" not in c0
+    assert delta["span_n.bench.test_only"] == 1
+    assert delta["span_s.bench.test_only"] > 0
+    assert delta["stat.steps"] == 0 and delta["compiles"] == 0

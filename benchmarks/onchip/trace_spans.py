@@ -20,8 +20,8 @@ spans, programs and named scopes.
   only the instruction's text.
 
 Busy time and the window are ``trace_reduce``'s, to the nanosecond. The
-harness still reduces with ``trace_reduce``; PERF.md (section 7) says what
-wiring this reduction and its metrics into the harness takes.
+harness (``reduce_trace``) keeps ``trace_reduce``'s readings and adds this
+reduction's per-program and per-scope times, which metric readers take.
 """
 from __future__ import annotations
 
